@@ -13,7 +13,7 @@ import argparse
 import jax
 
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models.ssm import SSMConfig
 from repro.models.transformer import ModelConfig
 from repro.runtime.supervisor import RestartPolicy, Supervisor
@@ -54,7 +54,7 @@ def main():
     mesh = make_host_mesh()
 
     def run(attempt):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return train_loop.train(
                 cfg, src, args.steps, ckpt_dir=args.ckpt, save_every=50,
                 optimizer=args.optimizer, peak_lr=3e-4, warmup=20,

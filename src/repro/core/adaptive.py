@@ -55,13 +55,12 @@ import dataclasses
 import itertools
 import os
 from collections import defaultdict
-from concurrent.futures import ProcessPoolExecutor
 from pathlib import Path
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
 from .anomaly import Region, cluster_regions
 from .expressions import GridSpec
-from .sweep import AnomalyAtlas, Instance, sweep
+from .sweep import AnomalyAtlas, Instance, process_pool, sweep
 
 Point = Tuple[int, ...]
 
@@ -352,8 +351,7 @@ def adaptive_sweep(
     if backend == "process":
         # One pool across every round: refinement rounds are many small
         # sweeps, so per-round process start-up would dominate.
-        executor = ProcessPoolExecutor(
-            max_workers=shards or os.cpu_count() or 1)
+        executor = process_pool(shards or os.cpu_count() or 1)
 
     def run_round(idx: int, admitted: Sequence[Point]) -> bool:
         """Measure this host's slice; pull the rest from siblings.

@@ -32,40 +32,6 @@ from .base import ExecutionBackend, KernelOps, num_inputs
 #: accumulating dead entries.
 EXEC_MEMO_MAX = 512
 
-#: Opt-in persistent XLA compilation cache (the process-pool / multi-host
-#: story: workers inherit the env var and share compiled programs across
-#: processes and reruns instead of re-compiling per worker).
-XLA_CACHE_ENV = "REPRO_XLA_CACHE_DIR"
-
-_xla_cache_wired = False
-
-
-def _maybe_enable_compilation_cache() -> None:
-    """Point XLA's persistent compilation cache at $REPRO_XLA_CACHE_DIR.
-
-    Best-effort and once per process: older jax versions without the
-    config knob simply keep the in-memory jit cache (the executable memo
-    above it still works).
-    """
-    global _xla_cache_wired
-    if _xla_cache_wired:
-        return
-    _xla_cache_wired = True
-    path = os.environ.get(XLA_CACHE_ENV)
-    if not path:
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as cc)
-            cc.set_cache_dir(path)  # type: ignore[attr-defined]
-        except Exception:
-            pass
-
-
 def _swap(a):
     import jax.numpy as jnp
     return jnp.swapaxes(a, -1, -2)
@@ -76,6 +42,14 @@ def _mirror(t):
     return jnp.tril(t) + _swap(jnp.tril(t, -1))
 
 
+def _matmul(a, b):
+    """``a @ b``; float32 operands at full precision on a TPU too."""
+    import jax.numpy as jnp
+
+    from repro.kernels._mxu import precision
+    return jnp.matmul(a, b, precision=precision(a, b))
+
+
 class JnpOps(KernelOps):
     """Plain-jnp kernel vocabulary (batch-dim friendly: last-two-axes ops)."""
 
@@ -83,17 +57,17 @@ class JnpOps(KernelOps):
         return _swap(a)
 
     def gemm(self, a, b):
-        return a @ b
+        return _matmul(a, b)
 
     def syrk(self, a):
         import jax.numpy as jnp
-        return jnp.tril(a @ _swap(a))
+        return jnp.tril(_matmul(a, _swap(a)))
 
     def symm(self, s, b):
-        return _mirror(s) @ b
+        return _matmul(_mirror(s), b)
 
     def symm_r(self, b, s):
-        return b @ _mirror(s)
+        return _matmul(b, _mirror(s))
 
     def tri2full(self, t):
         return _mirror(t)
@@ -212,7 +186,6 @@ class JaxBackend(ExecutionBackend):
             collections.OrderedDict())
         self.memo_hits = 0
         self.memo_misses = 0
-        _maybe_enable_compilation_cache()
 
     # -- hooks -------------------------------------------------------------
     def ops(self) -> KernelOps:

@@ -353,4 +353,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import configure_compilation_cache
+    configure_compilation_cache()
     raise SystemExit(main())

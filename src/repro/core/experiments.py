@@ -50,6 +50,7 @@ from .sweep import (
     collect_unique_calls,
     get_spec,
     measure_instance,
+    process_pool,
     registered_names,
     sweep,
 )
@@ -117,9 +118,7 @@ def experiment1_random_search(
             else BlasRunner()
     executor = None
     if backend == "process":
-        from concurrent.futures import ProcessPoolExecutor
-        executor = ProcessPoolExecutor(
-            max_workers=shards or os.cpu_count() or 1)
+        executor = process_pool(shards or os.cpu_count() or 1)
     found: List[Instance] = []
     samples = 0
     wall = 0.0

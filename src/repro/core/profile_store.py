@@ -89,11 +89,8 @@ def current_fingerprint(backend: str = "blas",
     except KeyError:  # unregistered label (tests, foreign caches)
         on_device = backend in ("jax", "pallas")
     if on_device:
-        try:
-            import jax
-            device = jax.devices()[0].device_kind
-        except Exception:  # jax missing or no devices configured
-            device = "unknown"
+        import jax
+        device = jax.devices()[0].device_kind
     else:
         device = platform.machine() or "unknown"
     return HardwareFingerprint(backend=backend, device=device, dtype=dtype)

@@ -53,6 +53,7 @@ import argparse
 import dataclasses
 import functools
 import json
+import multiprocessing
 import os
 import re
 import sys
@@ -547,6 +548,18 @@ def _run_serial_fastpath(spec, points, runner, threshold, on_done,
     stats.add_memo_delta(memo0, memo_counts(runner))
 
 
+def process_pool(max_workers: int) -> ProcessPoolExecutor:
+    """A worker pool whose processes start fresh (``spawn``).
+
+    A forked worker would inherit this process's JAX runtime, and on a TPU
+    host the chip it holds — a chip belongs to one process. Spawned
+    workers import only what they unpickle.
+    """
+    return ProcessPoolExecutor(
+        max_workers=max_workers,
+        mp_context=multiprocessing.get_context("spawn"))
+
+
 def _run_process_pool(spec, points, runner_factory, threshold, shards,
                       chunk_size, on_done, executor=None,
                       fastpath: bool = True,
@@ -564,8 +577,7 @@ def _run_process_pool(spec, points, runner_factory, threshold, shards,
     """
     chunks = _chunked(points, chunk_size)
     own = executor is None
-    pool = executor if executor is not None else ProcessPoolExecutor(
-        max_workers=shards)
+    pool = executor if executor is not None else process_pool(shards)
     try:
         pending = {
             pool.submit(_measure_chunk, spec, c, runner_factory, threshold,
@@ -1265,7 +1277,8 @@ def main(argv: Optional[List[str]] = None) -> int:
           f"measured={res.n_measured} skipped={res.n_skipped} "
           f"anomalies={len(res.anomalies)} "
           f"({res.anomaly_rate:.1%}) in {res.wall_s:.1f}s "
-          f"[{res.instances_per_s:.1f} inst/s]")
+          f"[{res.instances_per_s:.1f} inst/s] "
+          f"device={atlas.fingerprint.device!r}")
     if res.fastpath is not None and res.n_measured:
         print(f"fastpath: {res.fastpath.summary()}")
     regions = cluster_sweep(res.records, grid)
@@ -1566,4 +1579,6 @@ def _main_evaluate(args, spec, grid, points) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import configure_compilation_cache
+    configure_compilation_cache()
     raise SystemExit(main())

@@ -42,7 +42,7 @@ import os
 from pathlib import Path
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from .perfmodel import RooflineProfile
+from .perfmodel import RooflineProfile, hardware_spec
 from .profile_store import (
     HardwareFingerprint,
     ProfileStoreError,
@@ -183,11 +183,11 @@ def kernel_vmem_bytes(kind: str, dims: Sequence[int],
         from repro.kernels.chain_gemm import chain_gemm_vmem_bytes
         mp, kp, lp, np_ = padded_dims(kind, dims, d)
         return chain_gemm_vmem_bytes(mp, kp, lp, np_, bm=bm, bn=d["bn"],
-                                     dtype_bytes=dtype_bytes)
+                                     bk=d["bk"], dtype_bytes=dtype_bytes)
     if kind == "gemm_syrk":
         from repro.kernels.chain_gemm import gemm_syrk_vmem_bytes
         mp, kp, lp = padded_dims(kind, dims, d)
-        return gemm_syrk_vmem_bytes(mp, kp, lp, bm=bm,
+        return gemm_syrk_vmem_bytes(mp, kp, lp, bm=bm, bk=d["bk"],
                                     dtype_bytes=dtype_bytes)
     raise ValueError(f"kernel kind {kind!r} is not tunable")
 
@@ -308,9 +308,10 @@ def prune_candidates(
 
     Three rejection rules, applied in order and all *before* any timing:
 
-    1. **vmem** — :func:`kernel_vmem_bytes` above the hardware budget
-       (``profile.hw.vmem_bytes``). Such a config would spill or fail to
-       compile; timing it is a wasted measurement by construction.
+    1. **vmem** — :func:`kernel_vmem_bytes` above the kernel VMEM budget
+       (``profile.hw.vmem_bytes``; without a profile, this device's
+       :func:`~repro.core.perfmodel.hardware_spec`, the number the
+       ``ops.*`` wrappers guard with). Mosaic refuses such a config.
     2. **padding** — a block edge strictly larger than the dim it tiles
        (after MXU-128 rounding). The extra work is pure zero-padding; the
        same-shape 128 block dominates it.
@@ -325,11 +326,16 @@ def prune_candidates(
     re-appended if the cap or the roofline rule dropped it, so the
     measured winner can never lose to the default silently.
     """
-    profile = profile or RooflineProfile()
+    if profile is None:
+        # The kernels' roofline is v5e's wherever they run (interpret mode
+        # stands in for it); the VMEM budget is this device's.
+        profile = RooflineProfile()
+        budget = hardware_spec().vmem_bytes
+    else:
+        budget = profile.hw.vmem_bytes
     dims = tuple(int(d) for d in dims)
     if candidates is None:
         candidates = candidate_configs(kind, dims)
-    budget = profile.hw.vmem_bytes
     default = dict(DEFAULT_CONFIGS[kind])
     kept: List[Tuple[float, Tuple[Tuple[str, int], ...], Dict[str, int]]] = []
     rejected: List[RejectedCandidate] = []

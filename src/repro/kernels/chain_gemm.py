@@ -14,8 +14,10 @@ a persistent VMEM scratch of shape (bm, L), then every j-step contracts it
 with ``C[:, j]``. B and C stream through VMEM in (bk/bl)-sized slabs via
 ``lax.fori_loop`` + ``pl.ds`` dynamic slices.
 
-VMEM bound: bm·L fp32 scratch + slabs. With bm=128, L ≤ 8192 → ≤ 4 MiB.
-``ops.chain_gemm`` falls back to two ``gemm`` calls above the bound.
+VMEM: B is resident whole, next to the (bm, L) fp32 scratch and the
+double-buffered A/C/output blocks (:func:`chain_gemm_vmem_bytes`).
+``ops.chain_gemm`` falls back to two ``gemm`` calls where that exceeds the
+device's kernel VMEM budget.
 """
 
 from __future__ import annotations
@@ -29,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._checks import check_divisible, check_same
+from ._mxu import dot
 
 
 def _chain_kernel(a_ref, b_ref, c_ref, o_ref, m1_ref, *, bk: int, bl: int):
@@ -42,8 +45,7 @@ def _chain_kernel(a_ref, b_ref, c_ref, o_ref, m1_ref, *, bk: int, bl: int):
         def k_body(kk, acc):
             a_slab = a_ref[:, pl.ds(kk * bk, bk)]
             b_slab = b_ref[pl.ds(kk * bk, bk), :]
-            return acc + jnp.dot(a_slab, b_slab,
-                                 preferred_element_type=jnp.float32)
+            return acc + dot(a_slab, b_slab)
 
         acc0 = jnp.zeros((a_ref.shape[0], l_total), dtype=jnp.float32)
         m1_ref[...] = jax.lax.fori_loop(0, k_total // bk, k_body, acc0)
@@ -53,8 +55,7 @@ def _chain_kernel(a_ref, b_ref, c_ref, o_ref, m1_ref, *, bk: int, bl: int):
     def l_body(ll, acc):
         m1_slab = m1_ref[:, pl.ds(ll * bl, bl)]
         c_slab = c_ref[pl.ds(ll * bl, bl), :]
-        return acc + jnp.dot(m1_slab.astype(c_slab.dtype), c_slab,
-                             preferred_element_type=jnp.float32)
+        return acc + dot(m1_slab.astype(c_slab.dtype), c_slab)
 
     acc0 = jnp.zeros_like(o_ref, dtype=jnp.float32)
     out = jax.lax.fori_loop(0, l_total // bl, l_body, acc0)
@@ -99,15 +100,36 @@ def chain_gemm_pallas(
     )(a, b, c)
 
 
-def chain_gemm_vmem_bytes(m, k, l, n, bm=128, bn=128, *, dtype_bytes) -> int:
-    """Estimated VMEM residency for the fused kernel (wrapper fallback).
+def _highest_precision_bytes(bm, bk, dtype_bytes) -> int:
+    """Scoped VMEM that float32 dots at ``HIGHEST`` precision add.
 
-    ``dtype_bytes`` is keyword-required with no default: this estimate
-    used to default to 2 (bf16) while the pallas backend executes f32,
-    halving the footprint the VMEM pre-filter reasoned about. Callers
-    must pass the actual operand itemsize (``a.dtype.itemsize``).
+    Mosaic splits float32 slab operands into bf16 parts. Compiling for
+    v5e measured 0.23-0.25 MiB at 128 tiles and up to 1.3 MiB at 256
+    tiles, growing with the K-loop slab (bm, bk) and not with l; six
+    (bm, bk) float32 tiles bound every measurement.
     """
-    return (bm * k + k * l + l * bn) * dtype_bytes + bm * l * 4
+    return 6 * bm * bk * 4 if dtype_bytes == 4 else 0
+
+
+def chain_gemm_vmem_bytes(m, k, l, n, bm=128, bn=128, bk=128, *,
+                          dtype_bytes) -> int:
+    """VMEM the fused kernel needs on a TPU (the wrapper's fallback test).
+
+    Mosaic double-buffers every block whose index moves across the grid
+    (the A row-block, the C column-block, the output block) and holds the
+    grid-invariant B block once. On top come the (bm, l) fp32 scratch and
+    two (bm, l) fp32 values live in the K loop (its carry and the product
+    added to it), and for float32 operands the split of ``HIGHEST``
+    precision. Compiling for v5e matches this count at every boundary
+    shape in tests/test_tpu_compile.py; where one K slab makes the loop
+    trivial it over-counts, and the wrapper then falls back needlessly.
+
+    ``dtype_bytes`` is keyword-required with no default: callers must
+    pass the actual operand itemsize (``a.dtype.itemsize``).
+    """
+    blocks = 2 * (bm * k + l * bn + bm * bn) * dtype_bytes
+    return (blocks + k * l * dtype_bytes + 3 * bm * l * 4
+            + _highest_precision_bytes(bm, bk, dtype_bytes))
 
 
 def _gemm_syrk_kernel(ii_ref, jj_ref, a_i_ref, a_j_ref, b_ref, o_ref,
@@ -122,8 +144,7 @@ def _gemm_syrk_kernel(ii_ref, jj_ref, a_i_ref, a_j_ref, b_ref, o_ref,
         def k_body(kk, acc):
             a_slab = a_ref[:, pl.ds(kk * bk, bk)]
             b_slab = b_ref[pl.ds(kk * bk, bk), :]
-            return acc + jnp.dot(a_slab, b_slab,
-                                 preferred_element_type=jnp.float32)
+            return acc + dot(a_slab, b_slab)
 
         acc0 = jnp.zeros((a_ref.shape[0], b_ref.shape[1]),
                          dtype=jnp.float32)
@@ -131,8 +152,7 @@ def _gemm_syrk_kernel(ii_ref, jj_ref, a_i_ref, a_j_ref, b_ref, o_ref,
 
     _m1(a_i_ref, m1i_ref)
     _m1(a_j_ref, m1j_ref)
-    acc = jnp.dot(m1i_ref[...], m1j_ref[...].T,
-                  preferred_element_type=jnp.float32)
+    acc = dot(m1i_ref[...], m1j_ref[...].T)
     rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 1)
     masked = jnp.where(rows >= cols, acc, 0.0)
@@ -193,7 +213,16 @@ def gemm_syrk_pallas(
     return jnp.tril(out)
 
 
-def gemm_syrk_vmem_bytes(m, k, l, bm=128, *, dtype_bytes) -> int:
-    """Estimated VMEM residency of the fused GEMM+SYRK kernel."""
-    return (2 * bm * k + k * l) * dtype_bytes + 2 * bm * l * 4 \
-        + bm * bm * dtype_bytes
+def gemm_syrk_vmem_bytes(m, k, l, bm=128, bk=128, *, dtype_bytes) -> int:
+    """VMEM the fused GEMM+SYRK kernel needs on a TPU.
+
+    The same count as :func:`chain_gemm_vmem_bytes`: the two A row-blocks
+    and the output block double-buffered, the grid-invariant B once, two
+    (bm, l) fp32 scratches, one (bm, l) fp32 value live in the K loop,
+    and the float32 ``HIGHEST`` split. Checked against the compiler at
+    bm = 128 only: at bm = 256 Mosaic needs 2.8 MiB (l = 1,024) to
+    10.5 MiB (l = 4,096) more than this in bf16.
+    """
+    blocks = 2 * (2 * bm * k + bm * bm) * dtype_bytes
+    return (blocks + k * l * dtype_bytes + 3 * bm * l * 4
+            + _highest_precision_bytes(bm, bk, dtype_bytes))

@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._checks import check_divisible, check_same
+from ._mxu import dot
 
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -28,9 +29,7 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += dot(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -65,7 +64,7 @@ def gemm_pallas(
     k_steps = k // bk
     extra = {}
     if pipeline:
-        extra["compiler_params"] = pltpu.TPUCompilerParams(
+        extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_gemm_kernel, k_steps=k_steps),

@@ -3,11 +3,10 @@
 Responsibilities:
   * pad operands to block multiples (MXU 128-alignment) and slice results —
     the quantization the perf model charges for is made explicit here;
-  * select ``interpret=True`` automatically off-TPU so the same call sites
-    work on this CPU container (kernel body runs in Python) and on real
-    TPUs (Mosaic);
-  * fall back to the jnp reference where a kernel's structural premise
-    doesn't hold (e.g. chain_gemm beyond its VMEM bound).
+  * compile with Mosaic on a TPU and run in interpret mode on the CPU (the
+    kernel body runs in Python there); any other platform is an error;
+  * fall back to two unfused kernels where a fused kernel would exceed the
+    device's kernel VMEM budget (:func:`repro.core.perfmodel.hardware_spec`).
 """
 
 from __future__ import annotations
@@ -31,7 +30,17 @@ from .syrk import syrk_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels run on 'tpu' (Mosaic) or 'cpu' (interpret "
+            f"mode), not on {platform!r}")
+    return platform == "cpu"
+
+
+def _vmem_budget() -> int:
+    from repro.core.perfmodel import hardware_spec
+    return hardware_spec().vmem_bytes
 
 
 def _pad_to(x: jax.Array, mults) -> jax.Array:
@@ -74,23 +83,18 @@ def symm(s_lower: jax.Array, b: jax.Array, bm: int = 128,
     return out[:m, :n]
 
 
-# Fused chain beyond this VMEM residency falls back to two GEMMs.
-_CHAIN_VMEM_LIMIT = 32 * 1024 * 1024
-
-
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "bl"))
 def chain_gemm(a: jax.Array, b: jax.Array, c: jax.Array, bm: int = 128,
                bn: int = 128, bk: int = 128, bl: int = 128) -> jax.Array:
-    m, k = a.shape
-    _, l = b.shape
+    m, _ = a.shape
     _, n = c.shape
-    need = chain_gemm_vmem_bytes(m, k, l, n, bm, bn,
-                                 dtype_bytes=a.dtype.itemsize)
-    if need > _CHAIN_VMEM_LIMIT:
-        return gemm(gemm(a, b), c)
     ap = _pad_to(a, (bm, bk))
     bp = _pad_to(b, (bk, bl))
     cp = _pad_to(c, (bl, bn))
+    need = chain_gemm_vmem_bytes(*ap.shape, *cp.shape, bm, bn, bk,
+                                 dtype_bytes=a.dtype.itemsize)
+    if need > _vmem_budget():
+        return gemm(gemm(a, b), c)
     out = chain_gemm_pallas(ap, bp, cp, bm=bm, bn=bn, bk=bk, bl=bl,
                             interpret=_interpret())
     return out[:m, :n]
@@ -100,14 +104,13 @@ def chain_gemm(a: jax.Array, b: jax.Array, c: jax.Array, bm: int = 128,
 def gemm_syrk(a: jax.Array, b: jax.Array, bm: int = 128,
               bk: int = 128) -> jax.Array:
     """Lower triangle of (A·B)(A·B)ᵀ, fused (GEMM+SYRK epilogue)."""
-    m, k = a.shape
-    _, l = b.shape
-    need = gemm_syrk_vmem_bytes(m, k, l, bm,
-                                dtype_bytes=a.dtype.itemsize)
-    if need > _CHAIN_VMEM_LIMIT:
-        return syrk(gemm(a, b))
+    m, _ = a.shape
     ap = _pad_to(a, (bm, bk))
     bp = _pad_to(b, (bk, 128))
+    need = gemm_syrk_vmem_bytes(*ap.shape, bp.shape[1], bm, bk,
+                                dtype_bytes=a.dtype.itemsize)
+    if need > _vmem_budget():
+        return syrk(gemm(a, b))
     out = gemm_syrk_pallas(ap, bp, bm=bm, bk=bk, interpret=_interpret())
     return out[:m, :m]
 
@@ -118,14 +121,12 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
                     logit_softcap: float = 0.0, window: int = 0,
                     bq: int = 128, bkv: int = 128) -> jax.Array:
     s = q.shape[2]
+    if s % 128:
+        raise ValueError(
+            f"flash_attention needs a sequence length divisible by 128, "
+            f"got {s}")
     if s % bq or s % bkv:
-        # Sequence not block-divisible: shrink blocks or use the reference.
-        if s % 128 == 0:
-            bq = bkv = 128
-        else:
-            return ref.flash_attention(q, k, v, causal=causal, scale=scale,
-                                       logit_softcap=logit_softcap,
-                                       window=window)
+        bq = bkv = 128
     return flash_attention_pallas(
         q, k, v, causal=causal, scale=scale, logit_softcap=logit_softcap,
         window=window, bq=bq, bkv=bkv, interpret=_interpret())

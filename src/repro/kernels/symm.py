@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._checks import check_divisible, check_same
+from ._mxu import dot
 
 
 def _symm_kernel(s_ref, b_ref, o_ref, acc_ref, *, k_steps: int, bm: int):
@@ -41,10 +42,7 @@ def _symm_kernel(s_ref, b_ref, o_ref, acc_ref, *, k_steps: int, bm: int):
     # Off-diagonal: stored block is (max(i,l), min(i,l)); transpose if the
     # logical block is in the upper triangle (l > i).
     eff = jnp.where(i == l, sym, jnp.where(i > l, tile, tile.T))
-    acc_ref[...] += jnp.dot(
-        eff.astype(b_ref.dtype), b_ref[...],
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += dot(eff.astype(b_ref.dtype), b_ref[...])
 
     @pl.when(l == k_steps - 1)
     def _flush():

@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._checks import check_divisible
+from ._mxu import dot
 
 
 def _syrk_kernel(ii_ref, jj_ref, a_ref, at_ref, o_ref, acc_ref,
@@ -39,9 +40,7 @@ def _syrk_kernel(ii_ref, jj_ref, a_ref, at_ref, o_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...], at_ref[...].T, preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += dot(a_ref[...], at_ref[...].T)
 
     @pl.when(pl.program_id(1) == k_steps - 1)
     def _flush():

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, SHAPES, get, normalize, shape_applicable
 from repro.launch import hlo as hlo_lib
 from repro.launch import specs as specs_lib
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.transformer import ModelConfig
 from repro.serve.decode import make_serve_step
 from repro.sharding.context import activation_sharding
@@ -76,7 +76,7 @@ def lower_cell(cfg: ModelConfig, shape_name: str, multi_pod: bool,
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
 
-    with set_mesh(mesh), activation_sharding(
+    with jax.set_mesh(mesh), activation_sharding(
             mesh, heads=not multi_pod):
         if shape.kind == "train":
             state, state_shard = specs_lib.abstract_train_state(

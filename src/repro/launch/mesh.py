@@ -17,30 +17,11 @@ gradient compression targets).
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; Auto is the pre-AxisType default.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def set_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh, portable across jax versions.
-
-    ``jax.set_mesh`` landed after jax 0.4.x; on older jax the Mesh object
-    itself is the context manager that establishes the ambient mesh for
-    sharding constraints. Use this everywhere instead of ``jax.set_mesh``
-    (same class of gate as the ``AxisType`` import above).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -18,7 +18,7 @@ import jax
 
 from repro.configs import get, get_smoke, normalize
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.runtime.supervisor import RestartPolicy, Supervisor
 from repro.sharding.context import activation_sharding
 from repro.train import loop as train_loop
@@ -52,7 +52,7 @@ def main(argv=None) -> int:
                          extra_specs=extra)
 
     def run(attempt: int):
-        with set_mesh(mesh), activation_sharding(mesh):
+        with jax.set_mesh(mesh), activation_sharding(mesh):
             return train_loop.train(
                 cfg, source, args.steps, ckpt_dir=args.ckpt,
                 optimizer=args.optimizer, peak_lr=args.lr, mesh=mesh)
@@ -66,4 +66,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import configure_compilation_cache
+    configure_compilation_cache()
     sys.exit(main())

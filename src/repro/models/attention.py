@@ -334,29 +334,23 @@ def planned_pv_right_first(t: int, s: int, head_dim: int,
     static Python ints), so under ``jax.jit`` the consult is amortised by
     the XLA compile cache: zero per-token cost.
 
-    Selection must never take down the serving path: any failure (or the
-    ``REPRO_SERVE_PLANNER=0`` kill-switch) falls back to the left
-    association the pre-planner code always used. For realistic decode
-    geometries (t=1, head_dim ≤ d_model) every shipped policy picks left
-    — right costs s·head_dim·d_model multiply–adds per head vs left's
-    s·head_dim — so the consult leaves decode numerics alone there; only
-    shapes where a cost model genuinely prefers right (e.g. quantization
-    effects on degenerate head_dim > d_model layouts, or wide
-    speculative-decoding chunks) switch, and both orders are allclose up
-    to float reassociation.
+    The ``REPRO_SERVE_PLANNER=0`` kill-switch keeps the left association
+    the pre-planner code always used; a planner failure raises. For
+    realistic decode geometries (t=1, head_dim ≤ d_model) every shipped
+    policy picks left — right costs s·head_dim·d_model multiply–adds per
+    head vs left's s·head_dim — so the consult leaves decode numerics
+    alone there; only shapes where a cost model genuinely prefers right
+    (e.g. quantization effects on degenerate head_dim > d_model layouts,
+    or wide speculative-decoding chunks) switch, and both orders are
+    allclose up to float reassociation.
     """
-    try:
-        from repro.serve.plan_cache import (
-            default_plan_service, planner_enabled)
-        if not planner_enabled():
-            return False
-        plan = default_plan_service().lookup(
-            "decattn", (t, s, head_dim, d_model))
-        first = plan.algorithm.calls[0]
-        # Right-first iff the first GEMM is V·Wo (its rows are the s axis).
-        return s != t and first.dims[0] == s
-    except Exception:
+    from repro.serve.plan_cache import default_plan_service, planner_enabled
+    if not planner_enabled():
         return False
+    plan = default_plan_service().lookup("decattn", (t, s, head_dim, d_model))
+    first = plan.algorithm.calls[0]
+    # Right-first iff the first GEMM is V·Wo (its rows are the s axis).
+    return s != t and first.dims[0] == s
 
 
 def pv_wo_output(p_attn: jax.Array, vq: jax.Array, wo_params: Params,

@@ -106,7 +106,11 @@ def generate(params: Any, cfg: ModelConfig, prompt: jax.Array,
     state = ServeState(caches=caches,
                        last_tokens=prompt[:, :1],
                        rng=jax.random.PRNGKey(seed))
-    step = jax.jit(make_serve_step(cfg, temperature=temperature))
+    # Donated state: each step writes its caches over the ones it read.
+    # Without it, async dispatch keeps up to three cache generations live
+    # (phi3-mini at batch 4 and a 2,048-token cache ran out of v5e HBM).
+    step = jax.jit(make_serve_step(cfg, temperature=temperature),
+                   donate_argnums=(0,))
     out = [prompt]
     # Teacher-forced prefill: feed prompt tokens, ignore predictions.
     for i in range(s0 - 1):

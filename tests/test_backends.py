@@ -139,6 +139,29 @@ def test_build_is_positional_and_matches_execute(name):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("name", ["jax", "pallas"])
+@pytest.mark.parametrize("dtype,want", [("float32", "HIGHEST"),
+                                        ("bfloat16", "DEFAULT")])
+@pytest.mark.parametrize("expr,point", [("aatb", (32, 16, 24)),
+                                        ("abcd", (32, 48, 64, 40, 32))])
+def test_every_dot_runs_at_the_dtypes_precision(name, dtype, want, expr,
+                                                point):
+    """A TPU's default precision rounds float32 matmul operands to
+    bfloat16; float32 programs must ask for HIGHEST at every dot."""
+    import re
+
+    import jax
+
+    be = _cheap(name, dtype=dtype)
+    for alg in REGISTRY[expr].algorithms(point):
+        operands = be.make_operands(alg)
+        args = [operands.get(i, operands[0])
+                for i in range(be.num_inputs(alg))]
+        text = jax.jit(be.build(alg)).lower(*args).as_text()
+        found = set(re.findall(r"precision = \[(\w+), (\w+)\]", text))
+        assert found == {(want, want)}, (alg.name, found)
+
+
 def test_reference_execute_equals_numpy_backend():
     alg = REGISTRY["abab"].algorithms((12, 9, 7))[0]
     be = NumpyBackend(reps=1, flush_cache=False)

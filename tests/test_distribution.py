@@ -163,14 +163,14 @@ def test_roofline_terms_and_bottleneck():
 def test_sharded_train_step_runs_on_host_mesh():
     from repro.configs import get_smoke
     from repro.data.pipeline import SyntheticLM
-    from repro.launch.mesh import make_host_mesh, set_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.sharding.context import activation_sharding
     from repro.train.train_step import make_train_state, make_train_step
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 local devices")
     mesh = make_host_mesh(model=2)
     cfg = get_smoke("olmoe_1b_7b")
-    with set_mesh(mesh), activation_sharding(mesh):
+    with jax.set_mesh(mesh), activation_sharding(mesh):
         state, _ = make_train_state(jax.random.PRNGKey(0), cfg)
         src = SyntheticLM(cfg.vocab, 32, 4)
         batch = {k: jnp.asarray(v) for k, v in src.batch_at(0).items()}

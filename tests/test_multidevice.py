@@ -11,20 +11,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# These scenarios are written against the unified mesh API
-# (jax.set_mesh + jax.sharding.AxisType, post-0.4.x): the child
-# processes construct explicit-axis-type meshes that older jax cannot
-# express, so on such jax they are skipped rather than failed — the same
-# version gate repro.launch.mesh applies to AxisType itself.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="multi-device scenarios need the unified mesh API "
-           "(jax.set_mesh/AxisType), not present on this jax",
-)
-
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -44,13 +30,13 @@ def test_sharded_train_step_8dev():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_smoke
         from repro.data.pipeline import SyntheticLM
-        from repro.launch.mesh import make_host_mesh, set_mesh
+        from repro.launch.mesh import make_host_mesh
         from repro.sharding.context import activation_sharding
         from repro.train.train_step import make_train_state, make_train_step
         assert jax.device_count() == 8
         mesh = make_host_mesh(model=2)
         cfg = get_smoke("glm4_9b")
-        with set_mesh(mesh), activation_sharding(mesh):
+        with jax.set_mesh(mesh), activation_sharding(mesh):
             state, _ = make_train_state(jax.random.PRNGKey(0), cfg)
             src = SyntheticLM(cfg.vocab, 32, 8)
             batch = {k: jnp.asarray(v) for k, v in src.batch_at(0).items()}
@@ -112,7 +98,7 @@ def test_tiny_mesh_dryrun_roofline_8dev():
                           head_dim=64, d_ff=512, vocab=4096,
                           tied_embeddings=True, remat="full")
         shape = ShapeSpec("t", 512, 8, "train")
-        with set_mesh(mesh), activation_sharding(mesh), \
+        with jax.set_mesh(mesh), activation_sharding(mesh), \
                 scan_util.unrolled():
             state, sshard = specs_lib.abstract_train_state(cfg, mesh)
             batch, bshard = specs_lib.abstract_batch(cfg, shape, mesh)
@@ -150,7 +136,7 @@ def test_serve_step_sharded_8dev():
                           head_dim=32, d_ff=256, vocab=2048,
                           tied_embeddings=True)
         shape = ShapeSpec("d", 256, 8, "decode")
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st, sshard, pshapes, pshard = \
                 specs_lib.abstract_serve_state(cfg, shape, mesh)
             step = make_serve_step(cfg)
