@@ -1,2 +1,2 @@
 """Benchmark harness — one module per paper table/figure + framework
-selection benches + roofline reader. Entry: python -m benchmarks.run"""
+selection benches. Entry: python -m benchmarks.run"""

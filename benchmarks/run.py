@@ -1,5 +1,5 @@
 """Benchmark entry point: one section per paper table/figure + the
-framework's own planner/SSD/Muon selection benches + the roofline reader.
+framework's own planner/SSD/Muon selection benches.
 
 Prints ``name,us_per_call,derived`` CSV rows to stdout (human-readable
 tables go to stderr). REPRO_BENCH_SCALE=full runs paper-scale sizes.
@@ -23,7 +23,6 @@ def main() -> None:
         muon_bench,
         pallas_bench,
         planner_bench,
-        roofline,
         serve_bench,
         ssd_bench,
         sweep_bench,
@@ -45,7 +44,6 @@ def main() -> None:
         ("serving plan cache (loadtest)", serve_bench.main),
         ("ssd dual-form selection", ssd_bench.main),
         ("muon NS association selection", muon_bench.main),
-        ("roofline (dry-run artifacts)", roofline.main),
     ]
     failures = 0
     for name, fn in sections:

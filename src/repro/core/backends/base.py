@@ -41,6 +41,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ...runtime.tracing import span
 from ..algorithms import Algorithm, Leaf, Step
 from ..flops import KernelCall
 
@@ -153,13 +154,19 @@ def walk_steps(steps: Sequence[Step], leaf_fetch: Callable[[int], object],
     (``ops.chain_gemm`` / ``ops.gemm_syrk``) as one launch; the fused
     intermediate is provably dead (the pattern check rejects any later
     use), so only the second step's output id is bound.
+
+    Each kernel call runs in a ``repro.step.<method>`` span, and each
+    transposed leaf in ``repro.step.transpose`` (:mod:`repro.runtime.tracing`).
     """
     inter: Dict[int, object] = {}
 
     def fetch(ref):
         if isinstance(ref, Leaf):
             a = leaf_fetch(ref.base)
-            return ops.transpose(a) if ref.transposed else a
+            if not ref.transposed:
+                return a
+            with span("step.transpose"):
+                return ops.transpose(a)
         return inter[ref]
 
     fused = ops.fused_kinds()
@@ -173,25 +180,37 @@ def walk_steps(steps: Sequence[Step], leaf_fetch: Callable[[int], object],
             if pattern is not None and pattern in fused:
                 nxt = steps[i + 1]
                 if pattern == "gemm+gemm":
-                    out = ops.chain_gemm(fetch(step.lhs), fetch(step.rhs),
-                                         fetch(nxt.rhs))
+                    a, b, c = fetch(step.lhs), fetch(step.rhs), fetch(nxt.rhs)
+                    with span("step.chain_gemm"):
+                        out = ops.chain_gemm(a, b, c)
                 else:
-                    out = ops.gemm_syrk(fetch(step.lhs), fetch(step.rhs))
+                    a, b = fetch(step.lhs), fetch(step.rhs)
+                    with span("step.gemm_syrk"):
+                        out = ops.gemm_syrk(a, b)
                 inter[nxt.out] = out
                 i += 2
                 continue
         kind = step.call.kind
         if kind == "gemm":
-            out = ops.gemm(fetch(step.lhs), fetch(step.rhs))
+            a, b = fetch(step.lhs), fetch(step.rhs)
+            with span("step.gemm"):
+                out = ops.gemm(a, b)
         elif kind == "syrk":
-            out = ops.syrk(fetch(step.lhs))
+            a = fetch(step.lhs)
+            with span("step.syrk"):
+                out = ops.syrk(a)
         elif kind == "symm":
+            a, b = fetch(step.lhs), fetch(step.rhs)
             if step.symm_side == "R":
-                out = ops.symm_r(fetch(step.lhs), fetch(step.rhs))
+                with span("step.symm_r"):
+                    out = ops.symm_r(a, b)
             else:
-                out = ops.symm(fetch(step.lhs), fetch(step.rhs))
+                with span("step.symm"):
+                    out = ops.symm(a, b)
         elif kind == "tri2full":
-            out = ops.tri2full(fetch(step.lhs))
+            a = fetch(step.lhs)
+            with span("step.tri2full"):
+                out = ops.tri2full(a)
         else:
             raise ValueError(kind)
         inter[step.out] = out
@@ -422,19 +441,23 @@ class ExecutionBackend:
 
         The protocol knobs live on the instance: BLAS-style backends flush
         the cache in ``_pre_rep`` (paper §3.4), JAX backends jit in
-        ``_timed_callable`` and block in ``_sync``.
+        ``_timed_callable`` and block in ``_sync``. The warm-up and each rep
+        run in a ``repro.backend.call`` span, which encloses the clock reads
+        so that the times recorded do not include its cost.
         """
         if operands is None:
             operands = self.make_operands(alg)
         reps = self.reps if reps is None else reps
         fn = self._timed_callable(alg, operands)
-        self._sync(fn())  # warm-up: library init / compile / page-in
+        with span("backend.call"):
+            self._sync(fn())  # warm-up: library init / compile / page-in
         ts: List[float] = []
         for _ in range(reps):
             self._pre_rep()
-            t0 = time.perf_counter()
-            self._sync(fn())
-            ts.append(time.perf_counter() - t0)
+            with span("backend.call"):
+                t0 = time.perf_counter()
+                self._sync(fn())
+                ts.append(time.perf_counter() - t0)
         return float(np.median(ts))
 
     def benchmark_call(self, call: KernelCall,

@@ -41,6 +41,7 @@ import warnings
 from pathlib import Path
 from typing import Callable, Dict, Optional, Tuple
 
+from ..runtime.tracing import span
 from .algorithms import Algorithm, enumerate_algorithms
 from .backends import get_backend, measure_seconds
 from .expr import Chain, bind_dims
@@ -239,43 +240,48 @@ class Planner:
         :class:`repro.serve.plan_cache.PlanCache` adds request coalescing
         on top so same-shape misses do the work exactly once).
         """
-        key = self._key(c, env)
-        gen = self._profile_generation()
-        with self._lock:
-            hit = self._cache.get(key)
-        if hit is not None and hit[0] == gen:
-            return hit[1]
-        algos = enumerate_algorithms(c, env)
-        # Capability-gated arguments: a profile handed to a policy that
-        # never reads one (flops/measured/roofline) is a select() error
-        # now, and the planner always *has* a resolved profile — so only
-        # forward it where it is meaningful.
-        ranked = select(
-            algos, self.discriminant,
-            profile=self.profile if self._policy.requires_profile else None,
-            dtype_bytes=self.dtype_bytes)
-        best = ranked[0]
-        plan = Plan(
-            algorithm=best,
-            fn=self.runner.build(best),
-            ranked=tuple(a.name for a in ranked),
-            discriminant=self.discriminant,
-        )
-        with self._lock:
-            self._cache[key] = (gen, plan)
-        return plan
+        with span("planner.plan"):
+            key = self._key(c, env)
+            gen = self._profile_generation()
+            with self._lock:
+                hit = self._cache.get(key)
+            if hit is not None and hit[0] == gen:
+                return hit[1]
+            with span("planner.enumerate"):
+                algos = enumerate_algorithms(c, env)
+            # Capability-gated arguments: a profile handed to a policy that
+            # never reads one (flops/measured/roofline) is a select() error
+            # now, and the planner always *has* a resolved profile — so
+            # only forward it where it is meaningful.
+            with span("planner.select"):
+                ranked = select(
+                    algos, self.discriminant,
+                    profile=(self.profile if self._policy.requires_profile
+                             else None),
+                    dtype_bytes=self.dtype_bytes)
+            best = ranked[0]
+            plan = Plan(
+                algorithm=best,
+                fn=self.runner.build(best),
+                ranked=tuple(a.name for a in ranked),
+                discriminant=self.discriminant,
+            )
+            with self._lock:
+                self._cache[key] = (gen, plan)
+            return plan
 
     def __call__(self, c: Chain, *arrays, env=None):
         """Plan and evaluate in one call (arrays follow chain leaf order,
         with Gram-pair leaves deduplicated: pass each distinct matrix once
         per its first occurrence index). With ``record=True`` the execution
         is timed and fed back into the live profile."""
-        plan = self.plan(c, env)
-        if not self.record:
-            return plan.fn(*arrays)
-        out, seconds = measure_seconds(plan.fn, *arrays)
-        self.observe(plan, seconds)
-        return out
+        with span("planner.call", request=True):
+            plan = self.plan(c, env)
+            if not self.record:
+                return plan.fn(*arrays)
+            out, seconds = measure_seconds(plan.fn, *arrays)
+            self.observe(plan, seconds)
+            return out
 
     # -- online refinement ------------------------------------------------
     def _recording_table(self) -> Optional[TableProfile]:

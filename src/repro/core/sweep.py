@@ -62,6 +62,7 @@ from concurrent.futures import FIRST_COMPLETED, ProcessPoolExecutor, ThreadPoolE
 from pathlib import Path
 from typing import Callable, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
+from ..runtime.tracing import span
 from .algorithms import Algorithm, Leaf
 from .anomaly import Classification, Region, classify, cluster_regions, region_summary
 from .arena import (
@@ -139,10 +140,11 @@ def _measure_prepared(point, algos, operands, runner,
     """Time + classify one point whose algorithms/operands are in hand."""
     times: Dict[str, float] = {}
     flops: Dict[str, int] = {}
-    for a in algos:
-        times[a.name] = runner.time_algorithm(a, operands)
-        flops[a.name] = a.flops
-    cls = classify(times, flops, threshold=threshold)
+    with span("sweep.point", request=True):
+        for a in algos:
+            times[a.name] = runner.time_algorithm(a, operands)
+            flops[a.name] = a.flops
+        cls = classify(times, flops, threshold=threshold)
     return Instance(tuple(int(x) for x in point), times, flops, cls)
 
 
@@ -173,14 +175,15 @@ def measure_instance(
     # only fall back to make_operands for an algorithm referencing a base
     # the dict lacks — not per algorithm, which would generate (and mostly
     # discard) a full operand set each time.
-    operands = runner.make_operands(algos[-1])
-    for a in algos:
-        if not _leaf_bases(a) <= operands.keys():
-            for k, v in runner.make_operands(a).items():
-                operands.setdefault(k, v)
-        times[a.name] = runner.time_algorithm(a, operands)
-        flops[a.name] = a.flops
-    cls = classify(times, flops, threshold=threshold)
+    with span("sweep.point", request=True):
+        operands = runner.make_operands(algos[-1])
+        for a in algos:
+            if not _leaf_bases(a) <= operands.keys():
+                for k, v in runner.make_operands(a).items():
+                    operands.setdefault(k, v)
+            times[a.name] = runner.time_algorithm(a, operands)
+            flops[a.name] = a.flops
+        cls = classify(times, flops, threshold=threshold)
     return Instance(tuple(int(x) for x in point), times, flops, cls)
 
 
@@ -522,17 +525,19 @@ def _run_serial_fastpath(spec, points, runner, threshold, on_done,
             on_done(ready.pop(emit_q.popleft()))
 
     def prepare(p):
-        t0 = _time.perf_counter()
-        algos = spec.algorithms(p)
-        operands = arena.operands(algos)
-        return p, algos, operands, _time.perf_counter() - t0
+        with span("sweep.prepare"):
+            t0 = _time.perf_counter()
+            algos = spec.algorithms(p)
+            operands = arena.operands(algos)
+            return p, algos, operands, _time.perf_counter() - t0
 
     with ThreadPoolExecutor(max_workers=1) as helper:
         nxt = helper.submit(prepare, order[0])
         for i in range(len(order)):
-            t_wait = _time.perf_counter()
-            p, algos, operands, prep_s = nxt.result()
-            waited = _time.perf_counter() - t_wait
+            with span("sweep.wait"):
+                t_wait = _time.perf_counter()
+                p, algos, operands, prep_s = nxt.result()
+                waited = _time.perf_counter() - t_wait
             stats.prep_s += prep_s
             # Preparation time not spent blocking here ran concurrently
             # with the previous point's timed region.
@@ -789,47 +794,48 @@ def sweep(
         if progress is not None:
             progress(len(measured), n_total, inst)
 
-    try:
-        if not todo:
-            pass
-        elif backend == "serial":
-            r = runner
-            if r is None:
-                if runner_factory is not None:
-                    r = runner_factory()
-                elif exec_backend is not None:
-                    # dtype is the device-backend knob (float32 default);
-                    # fixed-dtype CPU backends keep their own default.
-                    kw = {"reps": reps, "seed": seed}
-                    if backend_shard_mode(exec_backend) == "device":
-                        kw["dtype"] = dtype
-                    r = make_backend(exec_backend, **kw)
+    with span("sweep.pass"):
+        try:
+            if not todo:
+                pass
+            elif backend == "serial":
+                r = runner
+                if r is None:
+                    if runner_factory is not None:
+                        r = runner_factory()
+                    elif exec_backend is not None:
+                        # dtype is the device-backend knob (float32 default);
+                        # fixed-dtype CPU backends keep their own default.
+                        kw = {"reps": reps, "seed": seed}
+                        if backend_shard_mode(exec_backend) == "device":
+                            kw["dtype"] = dtype
+                        r = make_backend(exec_backend, **kw)
+                    else:
+                        r = BlasRunner(reps=reps, seed=seed)
+                if fp_on:
+                    _run_serial_fastpath(spec, todo, r, threshold, on_done,
+                                         stats)
                 else:
-                    r = BlasRunner(reps=reps, seed=seed)
-            if fp_on:
-                _run_serial_fastpath(spec, todo, r, threshold, on_done,
-                                     stats)
+                    _run_serial(spec, todo, r, threshold, on_done)
+            elif backend == "process":
+                if runner_factory is None:
+                    runner_factory = functools.partial(
+                        make_backend, exec_backend or "blas", reps=reps,
+                        seed=seed)
+                _run_process_pool(spec, todo, runner_factory, threshold,
+                                  shards or os.cpu_count() or 1, chunk_size,
+                                  on_done, executor=executor, fastpath=fp_on,
+                                  stats=stats)
+            elif backend == "jax":
+                _run_jax_devices(spec, todo, threshold, reps,
+                                 exec_backend or "jax", dtype, shards, on_done,
+                                 seed=seed, fastpath=fp_on, stats=stats)
             else:
-                _run_serial(spec, todo, r, threshold, on_done)
-        elif backend == "process":
-            if runner_factory is None:
-                runner_factory = functools.partial(
-                    make_backend, exec_backend or "blas", reps=reps,
-                    seed=seed)
-            _run_process_pool(spec, todo, runner_factory, threshold,
-                              shards or os.cpu_count() or 1, chunk_size,
-                              on_done, executor=executor, fastpath=fp_on,
-                              stats=stats)
-        elif backend == "jax":
-            _run_jax_devices(spec, todo, threshold, reps,
-                             exec_backend or "jax", dtype, shards, on_done,
-                             seed=seed, fastpath=fp_on, stats=stats)
-        else:
-            raise ValueError(
-                f"unknown backend {backend!r}; expected serial|process|jax")
-    finally:
-        if atlas is not None:
-            atlas.flush()
+                raise ValueError(
+                    f"unknown backend {backend!r}; expected serial|process|jax")
+        finally:
+            if atlas is not None:
+                atlas.flush()
 
     records = [cached.get(p) or measured[p] for p in want
                if p in cached or p in measured]
