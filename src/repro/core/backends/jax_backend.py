@@ -17,13 +17,16 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import os
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import numpy as np
 
+from ...runtime import tracing
 from ..algorithms import Algorithm
 from ..arena import algorithm_structural_key
+from ..tuning import ALLOWED_KEYS, shape_config
 from .base import ExecutionBackend, KernelOps, num_inputs
 
 #: Bound on each backend instance's executable memo. Far above any real
@@ -80,13 +83,14 @@ class PallasOps(JnpOps):
     fused tril/transpose is already bandwidth-bound (see
     :func:`repro.kernels.ops.tri2full`).
 
-    ``config_lookup(kind, dims) -> dict | None`` supplies tuned tile
-    configs (from a :class:`~repro.core.tuning.TuningTable`, or the
-    autotuner's per-candidate override); ``None``/missing keys fall back
-    to the kernels' built-in 128-edge defaults. Dims are read from the
-    operands' trailing axes, so the lookup sees per-example shapes under
-    vmap/jit tracing too. Unknown keys from a foreign table are dropped
-    via :data:`~repro.core.tuning.ALLOWED_KEYS` rather than crashing the
+    ``config_lookup(kind, dims) -> Mapping | None`` supplies tile configs
+    (from a :class:`~repro.core.tuning.TuningTable`, the autotuner's
+    per-candidate override, or :func:`~repro.core.tuning.shape_config`);
+    ``None``/missing keys fall back to the kernels' built-in 128-edge
+    defaults. Dims are read from the operands' trailing axes, so the
+    lookup sees per-example shapes under vmap/jit tracing too. Unknown
+    keys from a foreign table are dropped via
+    :data:`~repro.core.tuning.ALLOWED_KEYS` rather than crashing the
     kernel call.
 
     This vocabulary also advertises the two fused patterns
@@ -95,8 +99,8 @@ class PallasOps(JnpOps):
     ``REPRO_NO_FUSION`` is set.
     """
 
-    def __init__(self, config_lookup: Optional[
-            Callable[[str, Tuple[int, ...]], Optional[dict]]] = None):
+    def __init__(self, config_lookup: Optional[Callable[
+            [str, Tuple[int, ...]], Optional[Mapping[str, int]]]] = None):
         self._lookup = config_lookup
 
     def _cfg(self, kind: str, dims: Tuple[int, ...]) -> dict:
@@ -105,7 +109,6 @@ class PallasOps(JnpOps):
         cfg = self._lookup(kind, dims)
         if not cfg:
             return {}
-        from ..tuning import ALLOWED_KEYS
         allowed = ALLOWED_KEYS.get(kind, ())
         return {k: int(v) for k, v in cfg.items() if k in allowed}
 
@@ -321,9 +324,13 @@ class PallasBackend(JaxBackend):
     hardware fingerprint (written by ``calibrate --tune``) on first
     kernel dispatch; every gemm/syrk/symm/fused call then runs at the
     tuned tile config for its shape (nearest same-kind entry for unseen
-    shapes). Pass an explicit table, or ``tuning=None`` to pin the
-    built-in 128-edge defaults; ``REPRO_NO_TUNING=1`` kills lookup at
-    dispatch time regardless.
+    shapes). Where no table was found, or it has no entry of the kind, a
+    gemm/syrk/symm call runs at :func:`~repro.core.tuning.shape_config`'s
+    tiles, chosen from its shape and this backend's dtype; each such
+    dispatch with an edge over 128 adds to the ``repro.tiles.shaped``
+    counter (:mod:`repro.runtime.tracing`). Pass an explicit table, or
+    ``tuning=None`` to pin the built-in 128-edge defaults;
+    ``REPRO_NO_TUNING=1`` kills lookup at dispatch time regardless.
     """
 
     name = "pallas"
@@ -338,6 +345,9 @@ class PallasBackend(JaxBackend):
                          use_pallas=use_pallas, seed=seed)
         self._tuning = tuning          # "auto" | TuningTable | None
         self._tuning_resolved = tuning != "auto"
+        #: Only the auto-loaded state falls back to shape-chosen tiles; a
+        #: pinned table or ``None`` keeps the kernels' defaults.
+        self._shaped = tuning == "auto"
         self._override: Optional[Callable[
             [str, Tuple[int, ...]], Optional[dict]]] = None
         #: Bumped whenever the effective config lookup changes (table
@@ -349,6 +359,7 @@ class PallasBackend(JaxBackend):
         """Pin a :class:`~repro.core.tuning.TuningTable` (or ``None``)."""
         self._tuning = table
         self._tuning_resolved = True
+        self._shaped = False
         self._tuning_generation += 1
 
     def tuning_table(self):
@@ -379,16 +390,28 @@ class PallasBackend(JaxBackend):
             self._override = prev
             self._tuning_generation += 1
 
-    def _config_lookup(self, kind: str,
-                       dims: Tuple[int, ...]) -> Optional[dict]:
+    def _config_lookup(self, kind: str, dims: Tuple[int, ...]
+                       ) -> Optional[Mapping[str, int]]:
         if self._override is not None:
             return self._override(kind, dims)
         if os.environ.get("REPRO_NO_TUNING"):
             return None
         table = self.tuning_table()
-        if table is None:
+        if table is not None:
+            cfg = table.config(kind, dims)
+            if cfg is not None:
+                return cfg
+        if not self._shaped:
             return None
-        return table.config(kind, dims)
+        shaped = shape_config(kind, dims, self._dtype_bytes)
+        if shaped is not None:
+            tracing.count("tiles.shaped")
+        return shaped
+
+    @functools.cached_property
+    def _dtype_bytes(self) -> int:
+        import jax.numpy as jnp
+        return jnp.dtype(self.dtype).itemsize
 
     def _memo_generation(self) -> Tuple:
         """Fusion + tuning state a traced Pallas program bakes in."""

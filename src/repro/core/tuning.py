@@ -29,18 +29,22 @@ tuner (the measurement loop lives in :mod:`repro.kernels.autotune`):
 
 ``calibrate --tune --backend pallas`` writes the table;
 :class:`~repro.core.backends.jax_backend.PallasBackend` auto-loads it.
-Set ``REPRO_NO_TUNING=1`` to kill tuned-config lookup entirely (the
-kernels fall back to their 128³ defaults).
+Where the auto-loaded table has no entry of a kind, or there is no
+table, the backend takes :func:`shape_config`'s tiles, chosen from the
+shape alone. Set ``REPRO_NO_TUNING=1`` to kill both (the kernels fall
+back to their 128³ defaults).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import os
+import types
 from pathlib import Path
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 from .perfmodel import RooflineProfile, hardware_spec
 from .profile_store import (
@@ -190,6 +194,57 @@ def kernel_vmem_bytes(kind: str, dims: Sequence[int],
         return gemm_syrk_vmem_bytes(mp, kp, lp, bm=bm, bk=d["bk"],
                                     dtype_bytes=dtype_bytes)
     raise ValueError(f"kernel kind {kind!r} is not tunable")
+
+
+#: Tile axis → index of the dim it tiles, for the kinds
+#: :func:`shape_config` chooses tiles for. ``symm_r`` dispatches as
+#: ``symm``. The fused kinds stay at 128: their VMEM estimates do not match
+#: the compiler at larger tiles.
+SHAPED_AXES: Dict[str, Dict[str, int]] = {
+    "gemm": {"bm": 0, "bn": 1, "bk": 2},
+    "syrk": {"bm": 0, "bk": 1},
+    "symm": {"bm": 0, "bn": 1},
+}
+
+
+@functools.lru_cache(maxsize=65536)
+def shape_config(kind: str, dims: Tuple[int, ...],
+                 dtype_bytes: int) -> Optional[Mapping[str, int]]:
+    """Tiles chosen from the shape alone, for a lookup with no tuned entry.
+
+    Each tile axis takes the largest edge in :data:`BLOCK_CHOICES` that
+    pads its dim no further than the 128 rounding does, so the kernel
+    does the work it does at the 128-edge defaults in fewer grid steps;
+    a symm's row edge leaves S two row blocks at least.
+    While :func:`kernel_vmem_bytes` exceeds this device's kernel VMEM
+    budget the largest edge is halved (the 128 config always fits).
+
+    ``None`` where every edge stays 128, and for the kinds outside
+    :data:`SHAPED_AXES`: the kernels' defaults apply. Memoised, so an
+    eager dispatch pays one cache hit; the mapping returned is shared and
+    read-only, and holds only keys of :data:`ALLOWED_KEYS`.
+    """
+    axes = SHAPED_AXES.get(kind)
+    if axes is None:
+        return None
+    config = {}
+    for name, i in axes.items():
+        padded = _ceil_to(dims[i], 128)
+        edges = [e for e in BLOCK_CHOICES if _ceil_to(dims[i], e) == padded]
+        if kind == "symm" and name == "bm":
+            # The TPU compiler refuses a bfloat16 symm whose S is a single
+            # block wider than 128 (a RET_CHECK in its MXU transpose pass),
+            # so S keeps two row blocks at least.
+            edges = [e for e in edges if e == 128 or e < padded]
+        config[name] = max(edges)
+    budget = hardware_spec().vmem_bytes
+    while max(config.values()) > 128 and kernel_vmem_bytes(
+            kind, dims, config, dtype_bytes=dtype_bytes) > budget:
+        widest = max(config, key=config.get)
+        config[widest] //= 2
+    if max(config.values()) == 128:
+        return None
+    return types.MappingProxyType(config)
 
 
 def padded_flops(kind: str, dims: Sequence[int],

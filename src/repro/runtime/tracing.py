@@ -15,7 +15,8 @@ thread carry its ``id``.
 
 While a session records, two counters count JAX's compile events:
 ``jax.traces`` (a function traced to a jaxpr) and ``jax.compiles`` (a
-program compiled, or loaded from the persistent compile cache).
+program compiled, or loaded from the persistent compile cache), and
+:func:`count` adds to the program's own counters, ``repro.<name>``.
 
 :func:`snapshot` gives ``{span name: (count, total_s, self_s)}`` and
 ``{counter name: count}``; :func:`reset` clears both. They cover every
@@ -106,6 +107,15 @@ def span(name: str, request: bool = False, **attrs):
     if not _listening:
         _listen()
     return _Span(t0, PREFIX + name, request, attrs)
+
+
+def count(name: str) -> None:
+    """Add one to counter ``repro.<name>`` while a profiler session
+    records; nothing otherwise."""
+    if _recording():
+        name = PREFIX + name
+        with _lock:
+            _counters[name] = _counters.get(name, 0) + 1
 
 
 def _count_compile(event: str, duration: float, **kwargs) -> None:

@@ -279,6 +279,39 @@ def test_enumerated_gram_algorithms_fuse_and_stay_correct():
         np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-2)
 
 
+# ------------------------------------- shape-chosen tiles (no table) ---
+
+M, N, K = 1024, 768, 640
+
+
+@pytest.mark.parametrize("kind", ["gemm", "syrk", "symm", "symm_r"])
+def test_shape_chosen_tiles_match_numpy(kind):
+    """``PallasBackend`` with auto tuning and no table runs the kernels at
+    tiles chosen from the shape (512, 256 and 128 edges mixed here)."""
+    from repro.core.backends.jax_backend import PallasBackend
+    backend = PallasBackend(reps=1)
+    assert backend.tuning_table() is None
+    kops = backend.ops()
+    a, b = np.asarray(randf(M, K)), np.asarray(randf(M, N))
+    s = np.asarray(randf(M, M))
+    s = (s + s.T) / 2
+    if kind == "gemm":
+        tiles = kops._cfg("gemm", (M, N, K))
+        out, expect = kops.gemm(a, b[:K]), a @ b[:K]
+    elif kind == "syrk":
+        tiles = kops._cfg("syrk", (M, K))
+        out, expect = kops.syrk(a), np.tril(a @ a.T)
+    elif kind == "symm":
+        tiles = kops._cfg("symm", (M, N))
+        out, expect = kops.symm(np.tril(s), b), s @ b
+    else:
+        tiles = kops._cfg("symm", (M, K))
+        out, expect = kops.symm_r(a.T, np.tril(s)), a.T @ s
+    assert max(tiles.values()) == 512 and min(tiles.values()) < 512, tiles
+    np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4,
+                               atol=1e-3)
+
+
 # ------------------------------------------------------ flash attention ---
 
 @pytest.mark.parametrize("kwargs", [

@@ -19,6 +19,7 @@ from repro.core.profile_store import (
     SchemaVersionError,
 )
 from repro.core.tuning import (
+    ALLOWED_KEYS,
     BLOCK_CHOICES,
     DEFAULT_CONFIGS,
     TUNABLE_KINDS,
@@ -389,3 +390,142 @@ def test_tune_rejects_untunable_backend():
     from repro.core.calibrate import tune
     with pytest.raises(ValueError, match="tunable"):
         tune(backend="jax", grid="tiny")
+
+
+# ------------------------------------------------ shape-chosen tiles ---
+
+NS_WIDTHS_TILES = [
+    ("syrk", (3072, 3072), {"bm": 512, "bk": 512}),
+    ("syrk", (3072, 8192), {"bm": 512, "bk": 512}),
+    ("syrk", (4096, 4096), {"bm": 512, "bk": 512}),
+    ("syrk", (512, 4096), {"bm": 512, "bk": 512}),
+    ("syrk", (4096, 11008), {"bm": 512, "bk": 256}),
+    ("symm", (4096, 11008), {"bm": 512, "bn": 256}),
+    ("symm", (3072, 8192), {"bm": 512, "bn": 512}),
+    ("symm", (512, 4096), {"bm": 256, "bn": 512}),    # two row blocks
+    ("gemm", (1024, 768, 640), {"bm": 512, "bn": 256, "bk": 128}),
+]
+
+
+@pytest.mark.parametrize("kind,dims,expect", NS_WIDTHS_TILES,
+                         ids=lambda v: str(v))
+def test_shape_config_at_the_newton_schulz_widths(kind, dims, expect):
+    from repro.core.tuning import shape_config
+    for dtype_bytes in (2, 4):
+        assert shape_config(kind, dims, dtype_bytes=dtype_bytes) == expect
+    backend = PallasBackend(reps=1)
+    assert backend.tuning_table() is None
+    assert backend.ops()._cfg(kind, dims) == expect
+
+
+@pytest.mark.parametrize("kind", ["gemm", "syrk", "symm"])
+def test_shape_config_pads_no_further_than_128_and_fits_vmem(kind):
+    """Every dim from 1 to 1300, on each axis: the padded work is the
+    128-edge default's, and the tiles fit the kernel VMEM budget."""
+    from repro.core.perfmodel import hardware_spec
+    from repro.core.tuning import SHAPED_AXES, shape_config
+    budget = hardware_spec().vmem_bytes
+    arity = len(SHAPED_AXES[kind])
+    wide = 0
+    for d in range(1, 1301):
+        for dims in {(d,) * arity, (d,) + (4096,) * (arity - 1),
+                     (4096,) * (arity - 1) + (d,)}:
+            for dtype_bytes in (2, 4):
+                cfg = shape_config(kind, dims, dtype_bytes=dtype_bytes)
+                if cfg is None:
+                    continue
+                wide += 1
+                assert max(cfg.values()) > 128
+                assert set(cfg) <= set(ALLOWED_KEYS[kind])
+                assert padded_dims(kind, dims, cfg) == padded_dims(
+                    kind, dims, {}), (dims, cfg)
+                if kind == "symm" and cfg["bm"] > 128:
+                    assert padded_dims(kind, dims, {})[0] >= 2 * cfg["bm"]
+                assert kernel_vmem_bytes(kind, dims, cfg,
+                                         dtype_bytes=dtype_bytes) <= budget
+    assert wide
+
+
+def test_shape_config_halves_the_widest_edge_to_fit_vmem(monkeypatch):
+    from repro.core import tuning
+    dims = (3072, 8192)
+    # Fits 512×256 symm tiles in bf16 (2.9 MB) but not 512×512 (4.2 MB).
+    small = HardwareSpec(name="test", peak_flops=1e12, hbm_bw=1e11,
+                         link_bw=1e9, vmem_bytes=3_500_000)
+    assert kernel_vmem_bytes("symm", dims, {"bm": 512, "bn": 512},
+                             dtype_bytes=2) > small.vmem_bytes
+    monkeypatch.setattr(tuning, "hardware_spec", lambda: small)
+    tuning.shape_config.cache_clear()
+    try:
+        cfg = tuning.shape_config("symm", dims, dtype_bytes=2)
+        assert cfg == {"bm": 256, "bn": 512} or cfg == {"bm": 512,
+                                                        "bn": 256}
+        assert kernel_vmem_bytes("symm", dims, cfg,
+                                 dtype_bytes=2) <= small.vmem_bytes
+    finally:
+        tuning.shape_config.cache_clear()
+
+
+def test_shape_config_is_memoised_and_read_only():
+    from repro.core.tuning import shape_config
+    cfg = shape_config("gemm", (2048, 2048, 2048), dtype_bytes=2)
+    assert cfg is shape_config("gemm", (2048, 2048, 2048), dtype_bytes=2)
+    with pytest.raises(TypeError):
+        cfg["bm"] = 128
+
+
+@pytest.mark.parametrize("kind,dims", [
+    ("gemm", (100, 1100, 384)),       # every axis rounds to an odd 128
+    ("syrk", (384, 640)),
+    ("symm", (239, 1038)),            # 256 would leave S one block
+    ("chain_gemm", (1024, 1024, 1024, 1024)),
+    ("gemm_syrk", (1024, 1024, 1024)),
+    ("tri2full", (1024,)),
+])
+def test_shape_config_keeps_128_where_wider_pads_or_is_not_shaped(kind,
+                                                                   dims):
+    from repro.core.tuning import shape_config
+    assert shape_config(kind, dims, dtype_bytes=2) is None
+    assert PallasBackend(reps=1).ops()._cfg(kind, dims) == {}
+
+
+@pytest.mark.parametrize("pin", ["tuning_none", "set_tuning_none",
+                                 "kill_switch", "override"])
+def test_pinned_lookups_keep_the_128_defaults(pin, monkeypatch):
+    """``tuning=None``, ``set_tuning(None)``, ``REPRO_NO_TUNING=1`` and the
+    autotuner's override give what they gave before the shape rule."""
+    dims = (2048, 2048, 2048)
+    backend = PallasBackend(reps=1, tuning=None if pin == "tuning_none"
+                            else "auto")
+    if pin == "set_tuning_none":
+        backend.set_tuning(None)
+    if pin == "kill_switch":
+        monkeypatch.setenv("REPRO_NO_TUNING", "1")
+    if pin == "override":
+        with backend.tuning_override({("gemm", dims): {"bm": 256}}):
+            assert backend._config_lookup("gemm", dims) == {"bm": 256}
+            assert backend._config_lookup("gemm", (4096,) * 3) is None
+        return
+    assert backend._config_lookup("gemm", dims) is None
+    assert backend.ops()._cfg("syrk", (2048, 2048)) == {}
+
+
+@pytest.mark.parametrize("dims,expect", [
+    ((2048, 2048, 2048), {"bm": 256, "bn": 128, "bk": 256}),  # exact
+    ((1900, 2100, 2000), {"bm": 256, "bn": 128, "bk": 256}),  # nearest
+])
+def test_a_table_entry_wins_over_the_shape_rule(dims, expect, tmp_path,
+                                                monkeypatch):
+    monkeypatch.setenv("REPRO_PROFILE_DIR", str(tmp_path))
+    from repro.core.profile_store import current_fingerprint
+    table = TuningTable()
+    table.set("gemm", (2048, 2048, 2048), TunedEntry(
+        config=dict(expect), seconds=1.0, default_seconds=1.0, timed=1,
+        pruned=0))
+    save_tuning_table(table, current_fingerprint(backend="pallas",
+                                                 dtype="float32"))
+    backend = PallasBackend(reps=1)
+    assert backend._config_lookup("gemm", dims) == expect
+    # A kind the table has no entry of takes the shape rule.
+    assert backend._config_lookup("syrk", (2048, 2048)) == {"bm": 512,
+                                                            "bk": 512}
