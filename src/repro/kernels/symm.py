@@ -2,10 +2,11 @@
 
 The paper's SYMM halves memory traffic for S by reading one triangle. On
 TPU we do the same at block granularity: the BlockSpec index map fetches
-S-block ``(max(i,l), min(i,l))`` — always from the lower triangle — and the
-kernel transposes the tile in-register when the logical block lies above
-the diagonal (``l > i``). Diagonal blocks are symmetrized in-register from
-their stored lower triangle.
+S-block ``(max(i,l), min(i,l))`` — always from the lower triangle — and
+each grid step does only the work its logical block ``(i, l)`` needs: below
+the diagonal (``l < i``) a plain product, above it (``l > i``) the product
+with the transposed tile, and on it the tile mirrored in-register from its
+stored lower triangle (whatever the strict upper triangle holds is ignored).
 
 HBM traffic for S is thus ``m(m+1)/2`` elements instead of ``m²`` — the
 exact asymmetry (SYMM vs GEMM efficiency) whose interplay with FLOP counts
@@ -33,16 +34,22 @@ def _symm_kernel(s_ref, b_ref, o_ref, acc_ref, *, k_steps: int, bm: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tile = s_ref[...]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 1)
-    lower = jnp.where(rows >= cols, tile, 0.0)
-    # Diagonal block: symmetrize the stored lower triangle.
-    sym = lower + jnp.where(rows > cols, tile, 0.0).T
-    # Off-diagonal: stored block is (max(i,l), min(i,l)); transpose if the
-    # logical block is in the upper triangle (l > i).
-    eff = jnp.where(i == l, sym, jnp.where(i > l, tile, tile.T))
-    acc_ref[...] += dot(eff.astype(b_ref.dtype), b_ref[...])
+    @pl.when(l < i)
+    def _below():
+        acc_ref[...] += dot(s_ref[...].astype(b_ref.dtype), b_ref[...])
+
+    @pl.when(l == i)
+    def _diagonal():
+        tile = s_ref[...]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 1)
+        sym = jnp.where(rows >= cols, tile, tile.T)
+        acc_ref[...] += dot(sym.astype(b_ref.dtype), b_ref[...])
+
+    @pl.when(l > i)
+    def _above():
+        # Stored block (l, i) is the transpose of the logical block (i, l).
+        acc_ref[...] += dot(s_ref[...].T.astype(b_ref.dtype), b_ref[...])
 
     @pl.when(l == k_steps - 1)
     def _flush():

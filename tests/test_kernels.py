@@ -70,27 +70,78 @@ def test_syrk_strictly_upper_is_zero():
 
 # ---------------------------------------------------------------- symm ---
 
-@pytest.mark.parametrize("m,n", [
-    (128, 128), (256, 64), (300, 120), (129, 33),
-])
-def test_symm_matches_ref(m, n):
-    s_low = jnp.asarray(np.tril(RNG.standard_normal((m, m))).astype(
+#: (m, n, bm, bn) at the tiles the chip uses: 2–4 row blocks of 256 or 512,
+#: so the kernel's below-, on- and above-diagonal branches all run; some m
+#: and n are padded up to the tile.
+WIDE_SYMM = [
+    (512, 128, 256, 128), (768, 512, 256, 256), (1000, 300, 256, 128),
+    (1024, 512, 512, 512), (1500, 512, 512, 256), (2048, 256, 512, 256),
+]
+
+
+def _wide(m, n, bm, bn):
+    return pytest.param(m, n, bm, bn, id=f"m{m}-n{n}-bm{bm}-bn{bn}")
+
+
+def _case_rng(m, n, bm, bn):
+    """The 128-tile cases draw from the module's stream, the wide cases
+    from a generator of their own, so the wide cases do not shift the
+    operands of later tests in this module."""
+    if (bm, bn) == (128, 128):
+        return RNG
+    return np.random.default_rng((m, n, bm, bn))
+
+
+def _sym_with_upper_garbage(m, rng):
+    """Lower triangle, and the same with large garbage in its strict
+    upper triangle (which covers that of every diagonal block)."""
+    low = np.tril(rng.standard_normal((m, m)))
+    garbage = low + np.triu(rng.standard_normal((m, m)) * 1e4, 1)
+    return low.astype(np.float32), garbage.astype(np.float32)
+
+
+@pytest.mark.parametrize("m,n,bm,bn", [
+    pytest.param(128, 128, 128, 128, id="128-128"),
+    pytest.param(256, 64, 128, 128, id="256-64"),
+    pytest.param(300, 120, 128, 128, id="300-120"),
+    pytest.param(129, 33, 128, 128, id="129-33"),
+] + [_wide(*c) for c in WIDE_SYMM])
+def test_symm_matches_ref(m, n, bm, bn):
+    rng = _case_rng(m, n, bm, bn)
+    s_low = jnp.asarray(np.tril(rng.standard_normal((m, m))).astype(
         np.float32))
-    b = randf(m, n)
+    b = jnp.asarray(rng.standard_normal((m, n)).astype(np.float32))
     np.testing.assert_allclose(
-        np.asarray(ops.symm(s_low, b)), np.asarray(ref.symm(s_low, b)),
-        rtol=1e-4, atol=1e-3)
+        np.asarray(ops.symm(s_low, b, bm=bm, bn=bn)),
+        np.asarray(ref.symm(s_low, b)), rtol=1e-4, atol=1e-3)
 
 
-def test_symm_ignores_strict_upper_garbage():
-    m, n = 192, 64
-    low = np.tril(RNG.standard_normal((m, m)))
-    garbage = low + np.triu(RNG.standard_normal((m, m)) * 100, 1)
-    b = randf(m, n)
-    out = ops.symm(jnp.asarray(garbage.astype(np.float32)), b)
-    expect = ref.symm(jnp.asarray(low.astype(np.float32)), b)
+@pytest.mark.parametrize("m,n,bm,bn", [
+    pytest.param(192, 64, 128, 128, id="192-64"),
+] + [_wide(*c) for c in WIDE_SYMM])
+def test_symm_ignores_strict_upper_garbage(m, n, bm, bn):
+    rng = _case_rng(m, n, bm, bn)
+    low, garbage = _sym_with_upper_garbage(m, rng)
+    b = jnp.asarray(rng.standard_normal((m, n)).astype(np.float32))
+    out = ops.symm(jnp.asarray(garbage), b, bm=bm, bn=bn)
+    expect = ref.symm(jnp.asarray(low), b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("m,n,bm,bn", [_wide(*c) for c in WIDE_SYMM])
+def test_symm_r_matches_ref(m, n, bm, bn):
+    """B·S through ``PallasOps.symm_r`` (the symm kernel on swapped
+    operands) at explicit tiles, with garbage above S's diagonal."""
+    from repro.core.backends.jax_backend import PallasOps
+    rng = _case_rng(m, n, bm, bn)
+    low, garbage = _sym_with_upper_garbage(m, rng)
+    b = rng.standard_normal((n, m)).astype(np.float32)
+    kops = PallasOps(lambda kind, dims: {"bm": bm, "bn": bn})
+    out = kops.symm_r(jnp.asarray(b), jnp.asarray(garbage))
+    full = low + np.tril(low, -1).T
+    np.testing.assert_allclose(np.asarray(out), b @ full, rtol=1e-4,
+                               atol=1e-3)
 
 
 # ---------------------------------------------------------- chain gemm ---
