@@ -21,13 +21,10 @@ from __future__ import annotations
 import functools
 import os
 import tempfile
-import time
 from pathlib import Path
 
 from repro.core import BlasRunner
 from repro.core.adaptive import adaptive_sweep
-from repro.core.backends import make_backend
-from repro.core.expressions import clear_algorithm_cache
 from repro.core.profile_store import current_fingerprint
 from repro.core.sweep import GRAM_AATB, AnomalyAtlas, GridSpec, sweep
 from repro.core.synthetic import (
@@ -82,96 +79,6 @@ def main():
          f"shards={shards};speedup={speedup:.2f}")
 
     adaptive_vs_dense()
-    fastpath_vs_legacy()
-
-
-class _FixedCostRunner:
-    """Deterministic sleep-kernel runner for the fastpath benchmark.
-
-    The timed "kernel" is a GIL-releasing ``time.sleep`` — so the pipelined
-    prepare thread can genuinely overlap it — while the *reported* seconds
-    are a pure function of the algorithm's FLOPs (with a planted SYRK skew
-    so classifications are non-trivial). Reported times are identical in
-    both modes, so the two atlases must match byte for byte.
-    """
-
-    def __init__(self, kernel_s: float):
-        self.kernel_s = kernel_s
-        self._ops = make_backend("numpy", reps=1, flush_cache=False, seed=7)
-
-    def make_operands(self, alg):
-        return self._ops.make_operands(alg)
-
-    def make_leaf_operand(self, ref, leading=()):
-        return self._ops.make_leaf_operand(ref, leading)
-
-    def time_algorithm(self, alg, operands=None, reps=None):
-        time.sleep(self.kernel_s)
-        skew = 1.35 if any(c.kind == "syrk" for c in alg.calls) else 1.0
-        return 1e-12 * alg.flops * skew
-
-
-def fastpath_vs_legacy():
-    """Serial fastpath vs legacy sweep on a fixed-cost kernel.
-
-    The synthetic kernel is self-calibrated so per-point kernel time is on
-    par with per-point prepare cost (enumeration + operand synthesis) —
-    the regime the fast path targets, where pipelining can hide nearly all
-    of the prep. The ``fastpath-smoke`` CI job gates on the emitted
-    ``speedup`` (≥ 1.3×) and ``atlas_identical`` (byte parity) fields.
-    """
-    # Dims large enough that operand synthesis dominates prepare cost —
-    # the component the arena and the prepare pipeline actually remove.
-    values = (256, 320, 384, 448) if FULL else (192, 256, 320)
-    grid = GridSpec.uniform(values, GRAM_AATB.ndims, name="fpbench")
-    points = grid.points()
-
-    # Calibrate the sleep so total kernel time per point ≈ prepare cost
-    # per point (measured cold: enumeration + one operand synthesis pass).
-    clear_algorithm_cache()
-    probe = make_backend("numpy", reps=1, flush_cache=False, seed=7)
-    t0 = time.perf_counter()
-    n_algos = 0
-    for p in points:
-        algos = GRAM_AATB.algorithms(p)
-        n_algos += len(algos)
-        probe.make_operands(algos[0])
-    prep_total = time.perf_counter() - t0
-    kernel_s = max(5e-4, prep_total / max(1, n_algos))
-
-    note(f"\n== fastpath vs legacy: {len(points)} AAᵀB instances, "
-         f"kernel {kernel_s * 1e3:.2f} ms ==")
-    results = {}
-    blobs = {}
-    with tempfile.TemporaryDirectory() as atlas_dir:
-        for mode, fp_on in (("fast", True), ("legacy", False)):
-            clear_algorithm_cache()  # don't gift enumeration to mode 2
-            d = Path(atlas_dir) / mode
-            atlas = AnomalyAtlas.open(
-                GRAM_AATB.name, current_fingerprint(), threshold=0.10,
-                directory=d)
-            results[mode] = sweep(GRAM_AATB, points,
-                                  runner=_FixedCostRunner(kernel_s),
-                                  atlas=atlas, fastpath=fp_on)
-            atlas.flush()
-            blobs[mode] = b"".join(
-                f.read_bytes() for f in sorted(d.rglob("*")) if f.is_file())
-    fast, legacy = results["fast"], results["legacy"]
-    identical = int(blobs["fast"] == blobs["legacy"] and bool(blobs["fast"]))
-    speedup = (fast.instances_per_s / legacy.instances_per_s
-               if legacy.instances_per_s else 0.0)
-
-    note(f"fast   : {fast.instances_per_s:8.1f} inst/s ({fast.wall_s:.2f}s)")
-    note(f"legacy : {legacy.instances_per_s:8.1f} inst/s "
-         f"({legacy.wall_s:.2f}s)")
-    note(f"speedup: {speedup:.2f}x  atlas_identical={identical}")
-    if fast.fastpath is not None:
-        note(f"fastpath: {fast.fastpath.summary()}")
-    emit("fastpath_vs_legacy",
-         fast.wall_s * 1e6 / max(1, fast.n_measured),
-         f"inst_per_s={fast.instances_per_s:.2f};"
-         f"legacy_inst_per_s={legacy.instances_per_s:.2f};"
-         f"speedup={speedup:.2f};atlas_identical={identical}")
 
 
 def adaptive_vs_dense():

@@ -122,7 +122,6 @@ _LAZY_EXPORTS = {
     "order_points_for_locality": ".arena",
     "algorithm_cache_stats": ".expressions",
     "clear_algorithm_cache": ".expressions",
-    "fastpath_enabled": ".sweep",
     # sweep engine (the `sweep` *function* stays module-scoped to keep the
     # submodule name unambiguous, mirroring calibrate)
     "SWEEP_GRIDS": ".expressions",
@@ -198,7 +197,7 @@ __all__ = [
     "cluster_regions", "scan_line",
     "FastPathStats", "OperandArena", "algorithm_structural_key",
     "arena_for", "order_points_for_locality",
-    "algorithm_cache_stats", "clear_algorithm_cache", "fastpath_enabled",
+    "algorithm_cache_stats", "clear_algorithm_cache",
     "SWEEP_GRIDS", "AnomalyAtlas", "AtlasError", "GridSpec", "Instance",
     "SweepResult", "atlas_path", "atlas_shard_path",
     "benchmark_unique_calls", "cluster_sweep",

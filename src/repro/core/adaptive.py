@@ -300,7 +300,6 @@ def adaptive_sweep(
     dtype: str = "float32",
     chunk_size: int = 8,
     progress: Optional[Callable[[int, int, Instance], None]] = None,
-    fastpath: Optional[bool] = None,
     seed: Optional[int] = None,
 ) -> AdaptiveResult:
     """Boundary-refining sweep: coarse seed, then budgeted frontier rounds.
@@ -310,8 +309,8 @@ def adaptive_sweep(
     trajectory budget but zero new measurements, which is what makes a
     resumed run honor the remaining budget instead of the original.
     ``rounds`` caps refinement rounds (``None`` = until budget or
-    convergence). Runner/backend knobs — including the fast-path switch
-    and operand ``seed`` — are forwarded verbatim to
+    convergence). Runner/backend knobs — including the operand ``seed``
+    — are forwarded verbatim to
     :func:`repro.core.sweep.sweep`; with ``backend="process"`` one pool is
     reused across every round, so worker arenas and executable memos
     persist across rounds too (refinement revisits neighbouring shapes).
@@ -362,7 +361,7 @@ def adaptive_sweep(
                     shards=shards, exec_backend=exec_backend, reps=reps,
                     dtype=dtype, chunk_size=chunk_size,
                     threshold=threshold, atlas=atlas, executor=executor,
-                    progress=progress, fastpath=fastpath, seed=seed)
+                    progress=progress, seed=seed)
         for rec in res.records:
             known[rec.point] = rec
         n_sib = n_missing = 0

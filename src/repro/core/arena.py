@@ -1,4 +1,4 @@
-"""Operand arena and fast-path accounting for measured sweeps (ISSUE 10).
+"""Operand arena and fast-path accounting for measured sweeps.
 
 Measured sweeps pay three per-point fixed costs that have nothing to do
 with the paper's timed region: operand allocation + RNG fill, algorithm
@@ -29,14 +29,13 @@ This module provides the pieces the sweep fast path composes:
   only in dimensions share one jitted callable (XLA re-traces per shape
   signature internally; the Python-side build + jit wrapper is reused).
 * :class:`FastPathStats` — the counter block surfaced by ``sweep()``
-  results, CLI progress, and ``benchmarks/sweep_bench.py``.
+  results and the CLI's ``fastpath:`` line.
 
 Everything degrades gracefully for duck-typed runners (the planted-mask
 oracles in :mod:`repro.core.synthetic`, deterministic test runners): a
-runner without ``make_leaf_operand`` is probed through its legacy
+runner without ``make_leaf_operand`` is probed through its whole-algorithm
 ``make_operands(alg)`` once per distinct leaf shape, and algorithms
-without real steps simply contribute no buffers — identical to the
-legacy path's ``setdefault`` merge semantics.
+without real steps simply contribute no buffers.
 """
 
 from __future__ import annotations
@@ -142,10 +141,9 @@ class OperandArena:
     """Shape-keyed operand buffers, bound to one runner.
 
     ``operands(algos)`` returns a ``{base: buffer}`` dict covering every
-    leaf of every algorithm — the union the legacy path built through
-    per-algorithm ``make_operands`` + ``setdefault`` merging — but each
-    distinct leaf shape is synthesized at most once for the arena's
-    lifetime. Buffers are handed to timed kernels read-only by
+    leaf of every algorithm, the first algorithm to name a base supplying
+    its buffer; each distinct leaf shape is synthesized at most once for
+    the arena's lifetime. Buffers are handed to timed kernels read-only by
     convention (no repro kernel writes its inputs); the cache-flush
     protocol is untouched because flushing happens inside the backend's
     per-rep hook, not at allocation time.
@@ -174,14 +172,14 @@ class OperandArena:
             self.nbytes += int(getattr(buf, "nbytes", 0))
 
     def _synthesize(self, ref: Leaf, alg: Algorithm) -> None:
-        """Fill the cache entry for ``ref`` (and, via the legacy
-        whole-algorithm fallback, any sibling leaves that come for free)."""
+        """Fill the cache entry for ``ref`` (and, via the whole-algorithm
+        fallback, any sibling leaves that come for free)."""
         make_leaf = getattr(self.runner, "make_leaf_operand", None)
         if make_leaf is not None:
             self._store(_leaf_key(ref), make_leaf(ref))
             return
-        # Duck-typed runner: probe through the legacy whole-algorithm
-        # entry point and harvest whatever it produced.
+        # Duck-typed runner: probe through the whole-algorithm entry
+        # point and harvest whatever it produced.
         produced = self.runner.make_operands(alg)
         for leaf in _iter_leaves(alg):
             key = _leaf_key(leaf)

@@ -378,26 +378,22 @@ class ExecutionBackend:
         """(backend, dtype) labels profiles/atlases are keyed by."""
         return (self.name, self.dtype)
 
-    def make_operands(self, alg: Algorithm,
-                      leading: Tuple[int, ...] = ()) -> Dict[int, object]:
+    def make_operands(self, alg: Algorithm) -> Dict[int, object]:
         """Fresh random inputs for every distinct leaf *base* of ``alg``.
 
         Leaves are stored untransposed (transposition is applied at fetch
         by the walker); symmetric leaves are symmetrized, since SYMM-based
         algorithms read only a triangle and would otherwise disagree with
-        their GEMM-based siblings. ``leading`` prefixes every operand's
-        shape (the vmap-batched path passes ``(batch,)``), so batched and
-        per-instance synthesis can never diverge.
+        their GEMM-based siblings.
         """
         out: Dict[int, object] = {}
         for step in alg.steps:
             for ref in (step.lhs, step.rhs):
                 if isinstance(ref, Leaf) and ref.base not in out:
-                    out[ref.base] = self.make_leaf_operand(ref, leading)
+                    out[ref.base] = self.make_leaf_operand(ref)
         return out
 
-    def make_leaf_operand(self, ref: Leaf,
-                          leading: Tuple[int, ...] = ()) -> object:
+    def make_leaf_operand(self, ref: Leaf) -> object:
         """One leaf's operand buffer (untransposed, symmetrized, placed).
 
         This is the unit the operand arena pools: with ``seed`` set the
@@ -409,9 +405,9 @@ class ExecutionBackend:
             ref.rows, ref.cols)
         rng = self.rng if self.seed is None else np.random.default_rng(
             (self.seed, ref.base, r, c))
-        a = rng.standard_normal((*leading, r, c))
+        a = rng.standard_normal((r, c))
         if ref.symmetric:
-            a = (a + np.swapaxes(a, -1, -2)) / 2.0
+            a = (a + a.T) / 2.0
         return self._asarray(a)
 
     def execute(self, alg: Algorithm,

@@ -1,16 +1,11 @@
-"""The ``jax`` and ``pallas`` backends: jit-able executors + vmap batching.
+"""The ``jax`` and ``pallas`` backends: jit-able executors.
 
 ``jax`` lowers every kernel kind to plain jnp (XLA picks the fusion);
 ``pallas`` routes gemm/syrk/symm through the hand-written Pallas TPU
 kernels in :mod:`repro.kernels` (Mosaic on TPU, interpret mode on CPU —
 the two must agree, which tests/test_kernels.py and the backend-parity
 gate assert). Both share the generic walker, so an algorithm's step DAG
-is traced once and jit/vmap treat it like any other jnp program.
-
-The batched path (:meth:`JaxBackend.build_batched` /
-:meth:`execute_batch`) vmaps the built callable over a leading instance
-axis — the many-instance serving shape: one algorithm, a batch of
-operand sets, one device dispatch instead of ``batch`` of them.
+is traced once and jit treats it like any other jnp program.
 """
 
 from __future__ import annotations
@@ -264,53 +259,6 @@ class JaxBackend(ExecutionBackend):
         some = next(iter(operands.values()))
         # fetch only ever reads base positions; fill the rest with any array
         return [operands.get(i, some) for i in range(n)]
-
-    # -- batched (vmap) execution -----------------------------------------
-    def make_batched_operands(self, alg: Algorithm,
-                              batch: int) -> Dict[int, object]:
-        """``batch`` independent operand sets, stacked on a leading axis."""
-        return self.make_operands(alg, leading=(batch,))
-
-    def build_batched(self, alg: Algorithm) -> Callable:
-        """vmap of :meth:`build` over a leading instance axis on every leaf.
-
-        One dispatch evaluates the algorithm for a whole batch of operand
-        sets — the serving-sweep shape, where thousands of small instances
-        would otherwise pay per-call dispatch each.
-        """
-        import jax
-        return jax.vmap(self.build(alg))
-
-    def execute_batch(self, alg: Algorithm,
-                      operands: Dict[int, object]):
-        """Evaluate ``alg`` over batched operands (one jitted vmap call)."""
-        import jax
-        fn = jax.jit(self.build_batched(alg))
-        return fn(*self._args(alg, operands))
-
-    def time_algorithm_batched(self, alg: Algorithm, batch: int = 32,
-                               operands: Optional[Dict[int, object]] = None,
-                               reps: Optional[int] = None) -> float:
-        """Median wall seconds for one *batched* evaluation of ``alg``.
-
-        Divide by ``batch`` for per-instance amortized time; contrast with
-        ``batch ×`` :meth:`time_algorithm` to see the dispatch amortization
-        the vmap path buys.
-        """
-        import jax
-        import time as _t
-
-        if operands is None:
-            operands = self.make_batched_operands(alg, batch)
-        args = self._args(alg, operands)
-        fn = jax.jit(self.build_batched(alg))
-        self._sync(fn(*args))  # warm-up: compile + page-in
-        ts = []
-        for _ in range(reps if reps is not None else self.reps):
-            t0 = _t.perf_counter()
-            self._sync(fn(*args))
-            ts.append(_t.perf_counter() - t0)
-        return float(np.median(ts))
 
 
 class PallasBackend(JaxBackend):

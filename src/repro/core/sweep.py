@@ -63,7 +63,6 @@ from pathlib import Path
 from typing import Callable, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 from ..runtime.tracing import span
-from .algorithms import Algorithm, Leaf
 from .anomaly import Classification, Region, classify, cluster_regions, region_summary
 from .arena import (
     FastPathStats,
@@ -106,24 +105,6 @@ from .runners import BlasRunner
 
 # --------------------------------------------------- instance measurement ---
 
-#: Kill-switch for the measurement fast path (arena + memo + pipelining).
-#: An env var rather than plumbing so process-pool workers and nested
-#: helpers inherit one decision; ``sweep --no-fastpath`` sets it.
-FASTPATH_ENV = "REPRO_NO_FASTPATH"
-
-
-def fastpath_enabled(flag: Optional[bool] = None) -> bool:
-    """Whether the measurement fast path is on (explicit flag wins)."""
-    if flag is not None:
-        return bool(flag)
-    return not os.environ.get(FASTPATH_ENV)
-
-
-def _leaf_bases(alg: Algorithm) -> set:
-    """Distinct operand base indices an algorithm's steps reference."""
-    return {ref.base for step in alg.steps for ref in (step.lhs, step.rhs)
-            if isinstance(ref, Leaf)}
-
 
 @dataclasses.dataclass
 class Instance:
@@ -159,32 +140,18 @@ def measure_instance(
 
     ``runner`` is any object with ``make_operands(alg) -> dict`` and
     ``time_algorithm(alg, operands) -> seconds`` — every registered
-    :class:`~repro.core.backends.ExecutionBackend` qualifies. With an
-    ``arena``, operand synthesis is served from the shape-keyed pool
-    (each distinct leaf buffer built once per arena lifetime); timing
-    semantics are untouched — the cache-flush protocol runs per rep
-    inside the backend either way.
+    :class:`~repro.core.backends.ExecutionBackend` qualifies. Operands
+    come from ``arena``, the shape-keyed pool that builds each distinct
+    leaf buffer once per arena lifetime; without one, a fresh arena
+    serves this call alone, so every call draws fresh operands. Timing is
+    the same either way: the cache-flush protocol runs per rep inside the
+    backend.
     """
     algos = spec.algorithms(point)
-    if arena is not None:
-        return _measure_prepared(point, algos, arena.operands(algos),
-                                 runner, threshold)
-    times: Dict[str, float] = {}
-    flops: Dict[str, int] = {}
-    # Leaves are shared across algorithms: synthesize operands once, and
-    # only fall back to make_operands for an algorithm referencing a base
-    # the dict lacks — not per algorithm, which would generate (and mostly
-    # discard) a full operand set each time.
-    with span("sweep.point", request=True):
-        operands = runner.make_operands(algos[-1])
-        for a in algos:
-            if not _leaf_bases(a) <= operands.keys():
-                for k, v in runner.make_operands(a).items():
-                    operands.setdefault(k, v)
-            times[a.name] = runner.time_algorithm(a, operands)
-            flops[a.name] = a.flops
-        cls = classify(times, flops, threshold=threshold)
-    return Instance(tuple(int(x) for x in point), times, flops, cls)
+    if arena is None:
+        arena = OperandArena(runner)
+    return _measure_prepared(point, algos, arena.operands(algos), runner,
+                             threshold)
 
 
 # ------------------------------------------------------------------ atlas ---
@@ -459,27 +426,23 @@ _worker_runner: Optional[Tuple[object, object]] = None  # (key, runner)
 
 
 def _measure_chunk(spec: ExpressionSpec, points: Sequence[Tuple[int, ...]],
-                   runner_factory: Callable[[], object],
-                   threshold: float, fastpath: bool = True,
+                   runner_factory: Callable[[], object], threshold: float,
                    ) -> Tuple[List[Instance], Dict[str, float]]:
     """Process-pool worker: measure one shard of points.
 
     Module-level (picklable); each worker builds its own runner — BLAS
     state, RNGs and cache-flush buffers are never shared across processes
     — and caches it for the worker's lifetime, so the 64 MB flush buffer
-    is zeroed once per worker rather than once per chunk. With the fast
-    path on, the runner's operand arena persists alongside it, so reuse
-    compounds across every chunk the worker sees. Returns the measured
-    instances plus this chunk's fast-path counter deltas.
+    is zeroed once per worker rather than once per chunk. The runner's
+    operand arena persists alongside it, so reuse compounds across every
+    chunk the worker sees. Returns the measured instances plus this
+    chunk's fast-path counter deltas.
     """
     global _worker_runner
     key = _factory_key(runner_factory)
     if _worker_runner is None or _worker_runner[0] != key:
         _worker_runner = (key, runner_factory())
     runner = _worker_runner[1]
-    if not (fastpath and fastpath_enabled()):
-        return ([measure_instance(spec, p, runner, threshold)
-                 for p in points], {})
     arena = arena_for(runner)
     stats = FastPathStats()
     a0, m0 = arena.snapshot(), memo_counts(runner)
@@ -494,22 +457,17 @@ def _chunked(seq: Sequence, size: int) -> List[Sequence]:
     return [seq[i:i + size] for i in range(0, len(seq), size)]
 
 
-def _run_serial(spec, points, runner, threshold, on_done) -> None:
-    for p in points:
-        on_done(measure_instance(spec, p, runner, threshold))
-
-
 def _run_serial_fastpath(spec, points, runner, threshold, on_done,
                          stats: FastPathStats) -> None:
-    """Arena + pipelined serial measurement (the ISSUE-10 fast path).
+    """Arena + pipelined serial measurement.
 
     Points are *measured* in locality order (lexicographic — identical to
-    row-major grid order, so dense sweeps keep their legacy measurement
-    order) while a single helper thread prepares point ``k+1``
-    (enumeration + arena operand synthesis) during point ``k``'s
-    GIL-releasing timed region. Instances are *emitted* strictly in
-    request order through a small reorder buffer, so atlas bytes and
-    progress callbacks are indistinguishable from the legacy path.
+    row-major grid order, so dense sweeps are measured in grid order)
+    while a single helper thread prepares point ``k+1`` (enumeration +
+    arena operand synthesis) during point ``k``'s GIL-releasing timed
+    region. Instances are *emitted* strictly in request order through a
+    small reorder buffer, so atlas bytes and progress callbacks follow
+    the requested order, whatever order the points were measured in.
     """
     from collections import deque
 
@@ -566,27 +524,25 @@ def process_pool(max_workers: int) -> ProcessPoolExecutor:
 
 
 def _run_process_pool(spec, points, runner_factory, threshold, shards,
-                      chunk_size, on_done, executor=None,
-                      fastpath: bool = True,
-                      stats: Optional[FastPathStats] = None) -> None:
+                      chunk_size, on_done, stats: FastPathStats,
+                      executor=None) -> None:
     """Shard points over a process pool (the BLAS fallback path).
 
     Chunks are submitted eagerly but results are drained as they complete,
     so the atlas keeps filling (and flushing) while workers run — a kill
     mid-pool still leaves every completed chunk on disk. An ``executor``
     passed in is reused and left open (callers measuring many point sets,
-    e.g. Experiment 1's sampling loop, pay process start-up once). With
-    the fast path on, each chunk is measured in locality order inside its
-    worker (whose arena persists across chunks) and per-chunk counter
-    deltas are merged into ``stats``.
+    e.g. Experiment 1's sampling loop, pay process start-up once). Each
+    chunk is measured in locality order inside its worker (whose arena
+    persists across chunks) and per-chunk counter deltas are merged into
+    ``stats``.
     """
     chunks = _chunked(points, chunk_size)
     own = executor is None
     pool = executor if executor is not None else process_pool(shards)
     try:
         pending = {
-            pool.submit(_measure_chunk, spec, c, runner_factory, threshold,
-                        fastpath)
+            pool.submit(_measure_chunk, spec, c, runner_factory, threshold)
             for c in chunks
         }
         while pending:
@@ -595,16 +551,15 @@ def _run_process_pool(spec, points, runner_factory, threshold, shards,
                 insts, chunk_stats = fut.result()
                 for inst in insts:
                     on_done(inst)
-                if stats is not None and chunk_stats:
-                    stats.merge(FastPathStats.from_dict(chunk_stats))
+                stats.merge(FastPathStats.from_dict(chunk_stats))
     finally:
         if own:
             pool.shutdown()
 
 
 def _run_jax_devices(spec, points, threshold, reps, exec_backend, dtype,
-                     shards, on_done, seed=None, fastpath: bool = True,
-                     stats: Optional[FastPathStats] = None) -> None:
+                     shards, on_done, stats: FastPathStats,
+                     seed=None) -> None:
     """Shard points across JAX devices, one pinned backend per device.
 
     Each device gets a round-robin shard and its own registry backend
@@ -613,11 +568,11 @@ def _run_jax_devices(spec, points, threshold, reps, exec_backend, dtype,
     dispatch releases the GIL while devices execute). On a 1-device host
     this degrades to the serial path. Results stream to ``on_done`` per
     instance (serialized by a lock), so the atlas keeps flushing and a
-    killed sweep still resumes from the last chunk. With the fast path
-    on, each device runner gets its own operand arena and its shard's
-    slice is measured in locality order; the executable memo lives on
-    the runner, so each algorithm *structure* is built/jitted once per
-    device instead of once per point.
+    killed sweep still resumes from the last chunk. Each device runner
+    gets its own operand arena and its shard's slice is measured in
+    locality order; the executable memo lives on the runner, so each
+    algorithm *structure* is built/jitted once per device instead of
+    once per point.
     """
     import threading
 
@@ -633,17 +588,14 @@ def _run_jax_devices(spec, points, threshold, reps, exec_backend, dtype,
     lock = threading.Lock()
 
     def work(runner, pts):
-        arena = arena_for(runner) if fastpath else None
-        if fastpath:
-            pts = order_points_for_locality(pts)
-        for p in pts:
+        arena = arena_for(runner)
+        for p in order_points_for_locality(pts):
             inst = measure_instance(spec, p, runner, threshold, arena=arena)
             with lock:
                 on_done(inst)
-        if stats is not None and arena is not None:
-            with lock:
-                stats.add_arena_delta((0, 0, 0), arena.snapshot())
-                stats.add_memo_delta((0, 0), memo_counts(runner))
+        with lock:
+            stats.add_arena_delta((0, 0, 0), arena.snapshot())
+            stats.add_memo_delta((0, 0), memo_counts(runner))
 
     with ThreadPoolExecutor(max_workers=len(devices)) as pool:
         futs = [pool.submit(work, r, pts)
@@ -663,9 +615,9 @@ class SweepResult:
     n_skipped: int            # points served from the atlas
     wall_s: float
     atlas_path: Optional[Path] = None
-    #: Fast-path counters (arena/memo hits, pipeline overlap); ``None``
-    #: when the legacy path ran (``--no-fastpath`` / REPRO_NO_FASTPATH).
-    fastpath: Optional[FastPathStats] = None
+    #: Fast-path counters: arena/memo hits, pipeline overlap.
+    fastpath: FastPathStats = dataclasses.field(
+        default_factory=FastPathStats)
 
     @property
     def n_points(self) -> int:
@@ -703,7 +655,6 @@ def sweep(
     dtype: str = "float32",
     executor=None,
     progress: Optional[Callable[[int, int, Instance], None]] = None,
-    fastpath: Optional[bool] = None,
     seed: Optional[int] = None,
 ) -> SweepResult:
     """Measure + classify a set of instances — the one measurement path.
@@ -736,15 +687,14 @@ def sweep(
     existing ``ProcessPoolExecutor`` to reuse across many sweep calls; it
     is left open for the caller.
 
-    ``fastpath`` controls the measurement fast path (operand arena,
-    executable memo, locality ordering, pipelined preparation): ``None``
-    (default) follows the ``REPRO_NO_FASTPATH`` kill-switch, ``True``/
-    ``False`` force it. Timing semantics are identical either way — only
-    per-point fixed costs (allocation, RNG fill, enumeration, re-tracing)
-    are amortised; the result's ``fastpath`` field carries the counters.
-    ``seed`` makes operand synthesis reproducible (each leaf a pure
-    function of ``(seed, base, shape)``) for runners the sweep builds
-    itself; explicit ``runner``/``runner_factory`` carry their own.
+    Every executor measures through the fast path (operand arena,
+    executable memo, locality ordering; pipelined preparation on the
+    serial executor). Only per-point fixed costs (allocation, RNG fill,
+    enumeration, re-tracing) are amortised, never the timed region; the
+    result's ``fastpath`` field carries the counters. ``seed`` makes
+    operand synthesis reproducible (each leaf a pure function of
+    ``(seed, base, shape)``) for runners the sweep builds itself;
+    explicit ``runner``/``runner_factory`` carry their own.
     """
     if atlas is not None and abs(atlas.threshold - threshold) > 1e-12:
         raise ValueError(
@@ -783,8 +733,7 @@ def sweep(
 
     measured: Dict[Tuple[int, ...], Instance] = {}
     n_total = len(todo)
-    fp_on = fastpath_enabled(fastpath)
-    stats = FastPathStats() if fp_on else None
+    stats = FastPathStats()
     t0 = _time.perf_counter()
 
     def on_done(inst: Instance) -> None:
@@ -812,11 +761,8 @@ def sweep(
                         r = make_backend(exec_backend, **kw)
                     else:
                         r = BlasRunner(reps=reps, seed=seed)
-                if fp_on:
-                    _run_serial_fastpath(spec, todo, r, threshold, on_done,
-                                         stats)
-                else:
-                    _run_serial(spec, todo, r, threshold, on_done)
+                _run_serial_fastpath(spec, todo, r, threshold, on_done,
+                                     stats)
             elif backend == "process":
                 if runner_factory is None:
                     runner_factory = functools.partial(
@@ -824,12 +770,11 @@ def sweep(
                         seed=seed)
                 _run_process_pool(spec, todo, runner_factory, threshold,
                                   shards or os.cpu_count() or 1, chunk_size,
-                                  on_done, executor=executor, fastpath=fp_on,
-                                  stats=stats)
+                                  on_done, stats, executor=executor)
             elif backend == "jax":
                 _run_jax_devices(spec, todo, threshold, reps,
                                  exec_backend or "jax", dtype, shards, on_done,
-                                 seed=seed, fastpath=fp_on, stats=stats)
+                                 stats, seed=seed)
             else:
                 raise ValueError(
                     f"unknown backend {backend!r}; expected serial|process|jax")
@@ -1177,12 +1122,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="pallas: disable fused adjacent-step dispatch "
                          "(sets REPRO_NO_FUSION) — every step launches "
                          "its own kernel")
-    ap.add_argument("--no-fastpath", action="store_true",
-                    help="disable the measurement fast path (operand "
-                         "arena, executable memo, pipelined preparation; "
-                         "sets REPRO_NO_FASTPATH) — timing semantics are "
-                         "identical either way, this is the paranoid "
-                         "bisect switch")
     ap.add_argument("--seed", type=int, default=None,
                     help="operand-synthesis seed: every leaf buffer "
                          "becomes a pure function of (seed, base, shape), "
@@ -1210,8 +1149,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         os.environ["REPRO_NO_TUNING"] = "1"
     if args.no_fusion:
         os.environ["REPRO_NO_FUSION"] = "1"
-    if args.no_fastpath:
-        os.environ[FASTPATH_ENV] = "1"
 
     spec = get_spec(args.expr)
     if args.grid in SWEEP_GRIDS or args.grid in spec.grids:
@@ -1285,7 +1222,7 @@ def main(argv: Optional[List[str]] = None) -> int:
           f"({res.anomaly_rate:.1%}) in {res.wall_s:.1f}s "
           f"[{res.instances_per_s:.1f} inst/s] "
           f"device={atlas.fingerprint.device!r}")
-    if res.fastpath is not None and res.n_measured:
+    if res.n_measured:
         print(f"fastpath: {res.fastpath.summary()}")
     regions = cluster_sweep(res.records, grid)
     print(region_summary(regions, res.n_points))
@@ -1448,16 +1385,14 @@ def _main_predict(args, spec, grid, points, atlas, dtype, fp) -> int:
                           seed=getattr(args, "seed", None))
     cached = load_default_profile(backend=args.backend, dtype=dtype)
     calls = collect_unique_calls(spec, points)
-    fp_on = fastpath_enabled()
-    arena = arena_for(runner) if fp_on else None
-    stats = FastPathStats() if fp_on else None
+    stats = FastPathStats()
     t0 = _time.perf_counter()
     profile, n_meas, n_reused = benchmark_unique_calls(
-        runner, calls, profile=cached, reps=args.reps, arena=arena,
-        stats=stats)
+        runner, calls, profile=cached, reps=args.reps,
+        arena=arena_for(runner), stats=stats)
     bench_s = _time.perf_counter() - t0
     save_profile(profile, fp, meta={"source": f"sweep:{spec.name}"})
-    if stats is not None and n_meas:
+    if n_meas:
         _note(f"fastpath: {stats.summary()}", args.quiet)
     predicted = predict_classifications(
         spec, points, profile, threshold=args.threshold,

@@ -213,29 +213,6 @@ def test_synthetic_algorithm_rejects_unknown_kind():
         synthetic_algorithm(KernelCall("trsm", (8, 8)))
 
 
-# ------------------------------------------------------------ vmap batching --
-
-@pytest.mark.parametrize("name", ["jax", "pallas"])
-def test_batched_execution_matches_per_instance(name):
-    be = get_backend(name, reps=1)
-    alg = REGISTRY["aatb"].algorithms((16, 8, 12))[1]
-    batch = 3
-    operands = be.make_batched_operands(alg, batch)
-    out = np.asarray(be.execute_batch(alg, operands))
-    assert out.shape[0] == batch
-    for i in range(batch):
-        single = {k: v[i] for k, v in operands.items()}
-        np.testing.assert_allclose(
-            out[i], np.asarray(be.execute(alg, single)),
-            rtol=1e-5, atol=1e-5)
-
-
-def test_batched_timing_runs():
-    be = get_backend("jax", reps=1)
-    alg = REGISTRY["aatb"].algorithms((16, 8, 12))[0]
-    assert be.time_algorithm_batched(alg, batch=2, reps=1) >= 0.0
-
-
 # --------------------------------------------------- sweep engine plumbing --
 
 FP = HardwareFingerprint(backend="blas", device="testdev", dtype="float64")

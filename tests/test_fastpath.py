@@ -1,9 +1,9 @@
-"""Measurement fast path (ISSUE 10): operand arena, executable memo,
-algorithm-enumeration LRU, and the pipelined serial sweep — all gated on
-bit-for-bit parity with the legacy measurement path (identical Instance
-records, byte-identical atlas files) plus kill/resume cleanliness."""
+"""Measurement fast path: operand arena, executable memo,
+algorithm-enumeration LRU, and the pipelined serial sweep — gated on what
+a sweep must reproduce (planted masks, closed-form times, byte-identical
+atlases across reruns) plus kill/resume cleanliness."""
 
-import os
+import functools
 
 import numpy as np
 import pytest
@@ -23,12 +23,10 @@ from repro.core.expressions import (
 from repro.core import expressions as expressions_mod
 from repro.core.profile_store import HardwareFingerprint
 from repro.core.sweep import (
-    FASTPATH_ENV,
     GRAM_AATB,
     AnomalyAtlas,
     GridSpec,
     benchmark_unique_calls,
-    fastpath_enabled,
     main as sweep_main,
     measure_instance,
     sweep,
@@ -44,8 +42,8 @@ GRID = GridSpec.uniform((32, 64, 96), GRAM_AATB.ndims, name="test")
 class CliffRunner:
     """Deterministic FLOP-proportional timer with a SYRK cliff at m >= 64.
 
-    Reported seconds are a pure function of the algorithm — identical in
-    fast and legacy modes, so the two must agree byte for byte.
+    Reported seconds are a pure function of the algorithm, so any two
+    sweeps of the same points must agree byte for byte.
     """
 
     def make_operands(self, alg):
@@ -64,19 +62,22 @@ class SeededFakeTimeNumpy(NumpyBackend):
     """Real (seeded) operand synthesis, deterministic reported time.
 
     Unlike :class:`CliffRunner` this drives genuine buffers through the
-    arena, so the parity check also covers operand plumbing.
+    arena, so the checks also cover operand plumbing.
     """
 
     def time_algorithm(self, alg, operands=None, reps=None):
         assert operands, f"operands never reached the runner for {alg.name}"
-        skew = 1.5 if any(c.kind == "syrk" for c in alg.calls) else 1.0
-        return 1e-12 * alg.flops * skew
+        return 1e-12 * alg.flops * _skew(alg)
 
 
-def _sweep_bytes(tmp_path, tag, spec, points, runner, fp_on):
+def _skew(alg):
+    return 1.5 if any(c.kind == "syrk" for c in alg.calls) else 1.0
+
+
+def _sweep_bytes(tmp_path, tag, spec, points, runner):
     path = tmp_path / f"{tag}.jsonl"
     atlas = AnomalyAtlas(path, FP, spec.name, 0.10)
-    res = sweep(spec, points, runner=runner, atlas=atlas, fastpath=fp_on)
+    res = sweep(spec, points, runner=runner, atlas=atlas)
     atlas.flush()
     return res, path.read_bytes()
 
@@ -84,36 +85,38 @@ def _sweep_bytes(tmp_path, tag, spec, points, runner, fp_on):
 # ------------------------------------------------------------------ parity --
 
 def test_fastpath_matches_legacy_on_planted_masks(tmp_path):
-    """Planted-mask oracles: identical records and atlas bytes per mask."""
+    """Planted-mask oracles: every verdict is the planted mask's, and two
+    sweeps with fresh runners write byte-identical atlases."""
     spec = PlantedSpec()
     grid = GridSpec.uniform(tuple(range(10, 110, 10)), spec.ndims,
                             name="planted")
     for name, mask in sorted(planted_masks(grid).items()):
-        fast, fast_b = _sweep_bytes(tmp_path, f"{name}-fast", spec,
-                                    grid.points(), MaskRunner(mask), True)
-        legacy, legacy_b = _sweep_bytes(tmp_path, f"{name}-legacy", spec,
-                                        grid.points(), MaskRunner(mask),
-                                        False)
-        assert fast.n_measured == legacy.n_measured == grid.n_points
-        a = [(r.point, r.times, r.flops, r.cls) for r in fast.records]
-        b = [(r.point, r.times, r.flops, r.cls) for r in legacy.records]
-        assert a == b, name
-        assert fast_b == legacy_b, name           # atlas parity, bytewise
-        assert fast.fastpath is not None and legacy.fastpath is None
+        first, first_b = _sweep_bytes(tmp_path, f"{name}-1", spec,
+                                      grid.points(), MaskRunner(mask))
+        second, second_b = _sweep_bytes(tmp_path, f"{name}-2", spec,
+                                        grid.points(), MaskRunner(mask))
+        assert first.n_measured == second.n_measured == grid.n_points
+        for r in first.records:
+            assert r.cls.is_anomaly == bool(mask(r.point)), (name, r.point)
+        assert first_b == second_b, name          # reruns agree, bytewise
+        assert isinstance(first.fastpath, FastPathStats)
 
 
 def test_fastpath_matches_legacy_with_real_operands(tmp_path):
-    """Seeded numpy operands through the arena: byte-identical atlases."""
+    """Seeded numpy operands through the arena: byte-identical atlases,
+    and every time the closed form the runner reports."""
     pts = GRID.points()
-    fast, fast_b = _sweep_bytes(
-        tmp_path, "fast", GRAM_AATB, pts,
-        SeededFakeTimeNumpy(reps=1, flush_cache=False, seed=11), True)
-    legacy, legacy_b = _sweep_bytes(
-        tmp_path, "legacy", GRAM_AATB, pts,
-        SeededFakeTimeNumpy(reps=1, flush_cache=False, seed=11), False)
-    assert fast_b == legacy_b
-    st = fast.fastpath
-    assert st is not None
+    first, first_b = _sweep_bytes(
+        tmp_path, "first", GRAM_AATB, pts,
+        SeededFakeTimeNumpy(reps=1, flush_cache=False, seed=11))
+    _, second_b = _sweep_bytes(
+        tmp_path, "second", GRAM_AATB, pts,
+        SeededFakeTimeNumpy(reps=1, flush_cache=False, seed=11))
+    assert first_b == second_b
+    for rec in first.records:
+        for alg in GRAM_AATB.algorithms(rec.point):
+            assert rec.times[alg.name] == 1e-12 * alg.flops * _skew(alg)
+    st = first.fastpath
     assert st.arena_hits > 0          # leaf shapes shared across points
     assert st.points_pipelined == len(pts) - 1
     assert 0.0 <= st.overlap_fraction <= 1.0
@@ -122,80 +125,84 @@ def test_fastpath_matches_legacy_with_real_operands(tmp_path):
 
 def test_fastpath_preserves_requested_order():
     pts = list(reversed(GRID.points()))
-    res = sweep(GRAM_AATB, pts, runner=CliffRunner(), fastpath=True)
+    res = sweep(GRAM_AATB, pts, runner=CliffRunner())
     assert [r.point for r in res.records] == pts
 
 
 def test_direct_measure_instance_with_arena_matches_legacy():
+    """A shared arena and the per-call arena give equal instances, whose
+    times are the closed form."""
     runner = SeededFakeTimeNumpy(reps=1, flush_cache=False, seed=3)
     arena = OperandArena(runner)
     for p in GRID.points()[:4]:
         via_arena = measure_instance(GRAM_AATB, p, runner, 0.10, arena=arena)
         plain = measure_instance(GRAM_AATB, p, runner, 0.10)
         assert via_arena == plain
+        assert plain.times == {
+            a.name: 1e-12 * a.flops * _skew(a)
+            for a in GRAM_AATB.algorithms(p)}
 
 
 # ------------------------------------------------------------- kill/resume --
 
 def test_killed_fastpath_sweep_resumes_to_legacy_identical_atlas(tmp_path):
     """Kill after 10 points, resume with a *fresh* runner (fresh arena):
-    the stitched atlas is byte-identical to an uninterrupted legacy sweep
-    — no arena or memo state leaks into resumed results."""
+    the stitched atlas is byte-identical to an uninterrupted sweep — no
+    arena or memo state leaks into resumed results."""
     path = tmp_path / "fast.jsonl"
     atlas = AnomalyAtlas(path, FP, GRAM_AATB.name, 0.10, chunk_size=5)
     res1 = sweep(GRAM_AATB, GRID.points(), runner=CliffRunner(),
-                 atlas=atlas, max_instances=10, fastpath=True)
+                 atlas=atlas, max_instances=10)
     assert res1.n_measured == 10
 
     atlas2 = AnomalyAtlas(path, FP, GRAM_AATB.name, 0.10)
     res2 = sweep(GRAM_AATB, GRID.points(), runner=CliffRunner(),
-                 atlas=atlas2, fastpath=True)
+                 atlas=atlas2)
     assert res2.n_skipped == 10
     assert res2.n_measured == GRID.n_points - 10
 
-    _, legacy_b = _sweep_bytes(tmp_path, "legacy", GRAM_AATB, GRID.points(),
-                               CliffRunner(), False)
-    assert path.read_bytes() == legacy_b
+    _, whole_b = _sweep_bytes(tmp_path, "whole", GRAM_AATB, GRID.points(),
+                              CliffRunner())
+    assert path.read_bytes() == whole_b
 
 
 def test_fastpath_budget_buys_first_points_in_request_order(tmp_path):
     """max_instances applies to the request-order todo *before* locality
     reordering — the budget semantics are unchanged by the fast path."""
     pts = list(reversed(GRID.points()))
-    res = sweep(GRAM_AATB, pts, runner=CliffRunner(), max_instances=5,
-                fastpath=True)
+    res = sweep(GRAM_AATB, pts, runner=CliffRunner(), max_instances=5)
     assert [r.point for r in res.records] == pts[:5]
 
 
-# ------------------------------------------------------------- kill-switch --
+# --------------------------------------------------------------- executors --
 
-def test_fastpath_enabled_flag_and_env(monkeypatch):
-    monkeypatch.delenv(FASTPATH_ENV, raising=False)
-    assert fastpath_enabled() is True
-    assert fastpath_enabled(False) is False
-    monkeypatch.setenv(FASTPATH_ENV, "1")
-    assert fastpath_enabled() is False
-    assert fastpath_enabled(True) is True        # explicit flag wins
-    res = sweep(GRAM_AATB, GRID.points()[:2], runner=CliffRunner())
-    assert res.fastpath is None                  # env took the legacy path
+@pytest.mark.parametrize("executor", ["serial", "process", "jax"])
+def test_every_executor_reports_fastpath_stats(executor):
+    pts = GRID.points()[:4]
+    if executor == "serial":
+        res = sweep(GRAM_AATB, pts, runner=CliffRunner())
+    elif executor == "process":
+        res = sweep(GRAM_AATB, pts, backend="process", shards=2,
+                    chunk_size=2,
+                    runner_factory=functools.partial(
+                        make_backend, "numpy", reps=1, flush_cache=False))
+    else:
+        pytest.importorskip("jax")
+        res = sweep(GRAM_AATB, pts, backend="jax", reps=1, shards=1)
+    assert res.n_measured == len(pts)
+    assert isinstance(res.fastpath, FastPathStats)
 
 
-def test_cli_no_fastpath_flag(tmp_path, monkeypatch, capsys):
-    monkeypatch.setenv(FASTPATH_ENV, "")         # registered for teardown
+def test_cli_no_fastpath_flag(tmp_path, capsys):
+    """The one measurement path prints its counters; the old switch is
+    gone, so argparse refuses it."""
     args = ["--expr", "aatb", "--grid", "smoke", "--reps", "1",
-            "--no-flush", "--atlas-dir", str(tmp_path / "a"), "--quiet",
-            "--no-fastpath"]
+            "--no-flush", "--atlas-dir", str(tmp_path / "a"), "--quiet"]
     assert sweep_main(args) == 0
-    out = capsys.readouterr().out
-    assert "fastpath:" not in out
-    assert os.environ[FASTPATH_ENV] == "1"       # pool workers inherit it
-
-    os.environ[FASTPATH_ENV] = ""                # re-arm for the second run
-    args2 = ["--expr", "aatb", "--grid", "smoke", "--reps", "1",
-             "--no-flush", "--atlas-dir", str(tmp_path / "b"), "--quiet"]
-    assert sweep_main(args2) == 0
-    out2 = capsys.readouterr().out
-    assert "fastpath:" in out2
+    assert "fastpath:" in capsys.readouterr().out
+    with pytest.raises(SystemExit) as exc:
+        sweep_main(args + ["--no-fastpath"])
+    assert exc.value.code == 2
 
 
 # --------------------------------------------------------- enumeration LRU --

@@ -184,14 +184,27 @@ def test_gemm_syrk_strictly_upper_is_zero():
 
 
 def test_gemm_syrk_falls_back_above_vmem_bound():
-    a, b = randf(64, 4096), randf(4096, 4096)
-    # f64 oracle + relative tolerance: entries here are ~1e5, and the f32
-    # accumulation-order difference between the fused and fallback paths
-    # is itself ~rtol-sized at this contraction depth.
-    m1 = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
-    np.testing.assert_allclose(
-        np.asarray(ops.gemm_syrk(a, b), np.float64), np.tril(m1 @ m1.T),
-        rtol=1e-2, atol=1e-1)
+    # A generator of its own: the inputs do not depend on what ran before.
+    rng = np.random.default_rng(4096)
+    k = 4096
+    a64 = rng.standard_normal((64, k)).astype(np.float32).astype(np.float64)
+    b64 = rng.standard_normal((k, 4096)).astype(np.float32).astype(np.float64)
+    m1 = a64 @ b64
+    want = np.tril(m1 @ m1.T)
+    # float32 rounding grows with the contraction depth and the largest
+    # entries (the diagonal, ~1.8e7 here), not with each entry: an entry
+    # near zero carries as much absolute error as any other. So the bound
+    # is sqrt(k) float32 epsilons of max|want| (about 30x the error seen).
+    atol = np.sqrt(k) * np.finfo(np.float32).eps * np.abs(want).max()
+    got = ops.gemm_syrk(jnp.asarray(a64, jnp.float32),
+                        jnp.asarray(b64, jnp.float32))
+    np.testing.assert_allclose(np.asarray(got, np.float64), want,
+                               rtol=0, atol=atol)
+    # The bound still catches a product that lost one 128-wide k-block.
+    short = m1 - a64[:, :128] @ b64[:128]
+    with pytest.raises(AssertionError):
+        np.testing.assert_allclose(np.tril(short @ short.T), want,
+                                   rtol=0, atol=atol)
 
 
 # --------------------------------------- pad/unpad path, every kernel ---

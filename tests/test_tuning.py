@@ -90,7 +90,7 @@ def test_vmem_over_budget_is_rejected_before_any_timing():
     timed_configs = []
 
     class SpyBackend(PallasBackend):
-        def make_operands(self, alg, leading=()):
+        def make_operands(self, alg):
             return {}
 
         def time_algorithm(self, alg, operands=None, reps=None):
@@ -307,7 +307,7 @@ def test_autotune_request_picks_measured_winner_and_counts():
     fake_times = {}
 
     class SpyBackend(PallasBackend):
-        def make_operands(self, alg, leading=()):
+        def make_operands(self, alg):
             return {}
 
         def time_algorithm(self, alg, operands=None, reps=None):
@@ -334,7 +334,7 @@ def test_autotune_probes_gemm_pipeline_on_winner_tile():
     seen_pipeline = []
 
     class SpyBackend(PallasBackend):
-        def make_operands(self, alg, leading=()):
+        def make_operands(self, alg):
             return {}
 
         def time_algorithm(self, alg, operands=None, reps=None):
