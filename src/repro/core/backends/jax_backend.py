@@ -48,6 +48,17 @@ def _matmul(a, b):
     return jnp.matmul(a, b, precision=precision(a, b))
 
 
+def _count_panel(s, b, n, cfg):
+    """Add the columns one S tile serves in this symm call to the
+    ``repro.symm.panel_cols`` counter, while a profiler session records."""
+    if tracing.recording():
+        import jax.numpy as jnp
+
+        from repro.kernels import ops as kops
+        tracing.count("symm.panel_cols", kops.symm_panel_cols(
+            n, jnp.result_type(s, b), **cfg))
+
+
 class JnpOps(KernelOps):
     """Plain-jnp kernel vocabulary (batch-dim friendly: last-two-axes ops)."""
 
@@ -125,12 +136,14 @@ class PallasOps(JnpOps):
     def symm(self, s, b):
         from repro.kernels import ops as kops
         cfg = self._cfg("symm", (s.shape[-2], b.shape[-1]))
+        _count_panel(s, b, b.shape[-1], cfg)
         return kops.symm(s, b, **cfg)
 
     def symm_r(self, b, s):
         from repro.kernels import ops as kops
         # B·S with S symmetric: (S·Bᵀ)ᵀ via the side-L kernel.
         cfg = self._cfg("symm", (s.shape[-2], b.shape[-2]))
+        _count_panel(s, b, b.shape[-2], cfg)
         return _swap(kops.symm(s, _swap(b), **cfg))
 
     def chain_gemm(self, a, b, c):

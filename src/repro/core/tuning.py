@@ -167,9 +167,10 @@ def kernel_vmem_bytes(kind: str, dims: Sequence[int],
     ``chain_gemm_vmem_bytes``-style accounting: streamed operand tiles
     are charged twice (Mosaic double-buffers the pipeline), fp32
     accumulator scratch is charged at 4 bytes regardless of the operand
-    dtype. The chain kinds delegate to the estimators that live next to
-    their kernels so the pre-filter and the wrapper fallback can never
-    disagree.
+    dtype. The chain kinds and symm delegate to the estimators that live
+    next to their kernels so the pre-filter and the wrapper can never
+    disagree; a symm is charged at the column panel its wrapper chooses
+    for this device's VMEM budget.
     """
     d = dict(DEFAULT_CONFIGS[kind], **config)
     bm = d.get("bm", 128)
@@ -181,8 +182,11 @@ def kernel_vmem_bytes(kind: str, dims: Sequence[int],
         bk = d["bk"]
         return 2 * (2 * bm * bk + bm * bm) * dtype_bytes + bm * bm * 4
     if kind == "symm":
-        bn = d["bn"]
-        return 2 * (bm * bm + 2 * bm * bn) * dtype_bytes + bm * bn * 4
+        from repro.kernels.symm import symm_panel, symm_vmem_bytes
+        _, np_ = padded_dims(kind, dims, d)
+        panel = symm_panel(np_, bm, d["bn"], dtype_bytes=dtype_bytes,
+                           budget=hardware_spec().vmem_bytes)
+        return symm_vmem_bytes(bm, panel, dtype_bytes=dtype_bytes)
     if kind == "chain_gemm":
         from repro.kernels.chain_gemm import chain_gemm_vmem_bytes
         mp, kp, lp, np_ = padded_dims(kind, dims, d)

@@ -6,7 +6,8 @@ Responsibilities:
   * compile with Mosaic on a TPU and run in interpret mode on the CPU (the
     kernel body runs in Python there); any other platform is an error;
   * fall back to two unfused kernels where a fused kernel would exceed the
-    device's kernel VMEM budget (:func:`repro.core.perfmodel.hardware_spec`).
+    device's kernel VMEM budget (:func:`repro.core.perfmodel.hardware_spec`),
+    and size the symm kernel's column panel to that budget.
 """
 
 from __future__ import annotations
@@ -25,7 +26,7 @@ from .chain_gemm import (
 )
 from .flash_attention import flash_attention_pallas
 from .gemm import gemm_pallas
-from .symm import symm_pallas
+from .symm import symm_pallas, symm_panel
 from .syrk import syrk_pallas
 
 
@@ -72,6 +73,15 @@ def syrk(a: jax.Array, bm: int = 128, bk: int = 128) -> jax.Array:
     return out[:m, :m]
 
 
+def symm_panel_cols(n: int, dtype, bm: int = 128, bn: int = 128) -> int:
+    """Output columns one S tile serves in :func:`symm` of an ``n``-column
+    B with operands of ``dtype``: the kernel's panel at this device's VMEM
+    budget."""
+    return symm_panel(-(-n // bn) * bn, bm, bn,
+                      dtype_bytes=jnp.dtype(dtype).itemsize,
+                      budget=_vmem_budget())
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bn"))
 def symm(s_lower: jax.Array, b: jax.Array, bm: int = 128,
          bn: int = 128) -> jax.Array:
@@ -79,7 +89,10 @@ def symm(s_lower: jax.Array, b: jax.Array, bm: int = 128,
     _, n = b.shape
     sp = _pad_to(s_lower, (bm, bm))
     bp = _pad_to(b, (bm, bn))
-    out = symm_pallas(sp, bp, bm=bm, bn=bn, interpret=_interpret())
+    out = symm_pallas(sp, bp, bm=bm, bn=bn,
+                      panel=symm_panel_cols(
+                          n, jnp.result_type(s_lower, b), bm, bn),
+                      interpret=_interpret())
     return out[:m, :n]
 
 

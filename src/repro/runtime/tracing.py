@@ -45,7 +45,8 @@ COMPILE_EVENTS = {
 }
 
 _OFF = contextlib.nullcontext()
-_recording = TraceMe.is_enabled
+#: Whether a profiler session records, so that spans and counters are on.
+recording = TraceMe.is_enabled
 _lock = threading.Lock()
 _spans: Dict[str, list] = {}
 _counters: Dict[str, int] = {}
@@ -101,7 +102,7 @@ class _Span:
 def span(name: str, request: bool = False, **attrs):
     """A context for ``repro.<name>``: recorded while a profiler session
     records, the shared no-op otherwise."""
-    if not _recording():
+    if not recording():
         return _OFF
     t0 = time.perf_counter()
     if not _listening:
@@ -109,18 +110,18 @@ def span(name: str, request: bool = False, **attrs):
     return _Span(t0, PREFIX + name, request, attrs)
 
 
-def count(name: str) -> None:
-    """Add one to counter ``repro.<name>`` while a profiler session
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to counter ``repro.<name>`` while a profiler session
     records; nothing otherwise."""
-    if _recording():
+    if recording():
         name = PREFIX + name
         with _lock:
-            _counters[name] = _counters.get(name, 0) + 1
+            _counters[name] = _counters.get(name, 0) + n
 
 
 def _count_compile(event: str, duration: float, **kwargs) -> None:
     name = COMPILE_EVENTS.get(event)
-    if name is not None and _recording():
+    if name is not None and recording():
         with _lock:
             _counters[name] = _counters.get(name, 0) + 1
 

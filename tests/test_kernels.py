@@ -79,8 +79,22 @@ WIDE_SYMM = [
 ]
 
 
+#: (m, n, bm, bn, panel): float32 shapes at which the wrapper's column
+#: panel (the output columns one S tile serves) takes each of its forms.
+PANEL_SYMM = [
+    (1024, 2560, 512, 128, 640),    # 4 panels of 5 blocks, S 2 row blocks
+    (1024, 5504, 512, 128, 512),    # n/bn = 43 prime: the 11th overhangs
+    (768, 4736, 256, 128, 2432),    # n/bn = 37 prime: 2 panels, ragged
+    (512, 1536, 256, 128, 1536),    # n inside one panel, S 2 row blocks
+]
+
+
 def _wide(m, n, bm, bn):
     return pytest.param(m, n, bm, bn, id=f"m{m}-n{n}-bm{bm}-bn{bn}")
+
+
+WIDE_CASES = [_wide(*c) for c in WIDE_SYMM] + [
+    _wide(*c[:4]) for c in PANEL_SYMM]
 
 
 def _case_rng(m, n, bm, bn):
@@ -105,7 +119,7 @@ def _sym_with_upper_garbage(m, rng):
     pytest.param(256, 64, 128, 128, id="256-64"),
     pytest.param(300, 120, 128, 128, id="300-120"),
     pytest.param(129, 33, 128, 128, id="129-33"),
-] + [_wide(*c) for c in WIDE_SYMM])
+] + WIDE_CASES)
 def test_symm_matches_ref(m, n, bm, bn):
     rng = _case_rng(m, n, bm, bn)
     s_low = jnp.asarray(np.tril(rng.standard_normal((m, m))).astype(
@@ -118,7 +132,7 @@ def test_symm_matches_ref(m, n, bm, bn):
 
 @pytest.mark.parametrize("m,n,bm,bn", [
     pytest.param(192, 64, 128, 128, id="192-64"),
-] + [_wide(*c) for c in WIDE_SYMM])
+] + WIDE_CASES)
 def test_symm_ignores_strict_upper_garbage(m, n, bm, bn):
     rng = _case_rng(m, n, bm, bn)
     low, garbage = _sym_with_upper_garbage(m, rng)
@@ -129,7 +143,7 @@ def test_symm_ignores_strict_upper_garbage(m, n, bm, bn):
                                rtol=1e-4, atol=1e-3)
 
 
-@pytest.mark.parametrize("m,n,bm,bn", [_wide(*c) for c in WIDE_SYMM])
+@pytest.mark.parametrize("m,n,bm,bn", WIDE_CASES)
 def test_symm_r_matches_ref(m, n, bm, bn):
     """B·S through ``PallasOps.symm_r`` (the symm kernel on swapped
     operands) at explicit tiles, with garbage above S's diagonal."""
@@ -142,6 +156,27 @@ def test_symm_r_matches_ref(m, n, bm, bn):
     full = low + np.tril(low, -1).T
     np.testing.assert_allclose(np.asarray(out), b @ full, rtol=1e-4,
                                atol=1e-3)
+
+
+@pytest.mark.parametrize("m,n,bm,bn,panel", PANEL_SYMM, ids=str)
+def test_symm_panel_cases_take_their_panels(m, n, bm, bn, panel):
+    """The panel cases above run at the panels they are named for."""
+    assert ops.symm_panel_cols(n, jnp.float32, bm, bn) == panel
+
+
+def test_symm_pallas_panel_overhangs_n_and_drops_the_rest():
+    """The kernel at an explicit panel that does not divide n: the last
+    panel's columns beyond n are dropped, the rest match ``ref.symm``."""
+    from repro.kernels.symm import symm_pallas
+    rng = np.random.default_rng(43)
+    low, garbage = _sym_with_upper_garbage(512, rng)
+    b = jnp.asarray(rng.standard_normal((512, 7 * 128)).astype(np.float32))
+    out = symm_pallas(jnp.asarray(garbage), b, bm=256, bn=128, panel=384,
+                      interpret=True)
+    assert out.shape == b.shape
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.symm(jnp.asarray(low), b)),
+                               rtol=1e-4, atol=1e-3)
 
 
 # ---------------------------------------------------------- chain gemm ---
@@ -266,6 +301,8 @@ def test_kernels_raise_valueerror_naming_dim_and_block():
         syrk_pallas(z((128, 100)), interpret=True)
     with pytest.raises(ValueError, match=r"n=100.*bn=128"):
         symm_pallas(z((128, 128)), z((128, 100)), interpret=True)
+    with pytest.raises(ValueError, match=r"panel=192.*bn=128"):
+        symm_pallas(z((128, 128)), z((128, 256)), panel=192, interpret=True)
     with pytest.raises(ValueError, match=r"l=100.*bl=128"):
         chain_gemm_pallas(z((128, 128)), z((128, 100)), z((100, 128)),
                           interpret=True)

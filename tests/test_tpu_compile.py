@@ -98,6 +98,25 @@ def test_syrk_and_symm_compile(mosaic, one_chip, tile):
     assert _kernels(symm.lower(a, a).compile()) == 1
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("m,n", [(3072, 3072), (3072, 8192), (4096, 4096),
+                                 (512, 4096), (4096, 11008)], ids=str)
+def test_symm_panel_compiles_at_the_newton_schulz_widths(mosaic, one_chip,
+                                                         m, n, dtype):
+    """At the tiles the backend takes there, the column panel the wrapper
+    sizes to the VMEM budget (wider than one block in bfloat16) is one
+    kernel the compiler accepts."""
+    from repro.core.tuning import shape_config
+    cfg = dict(shape_config("symm", (m, n),
+                            dtype_bytes=jnp.dtype(dtype).itemsize))
+    assert ops.symm_panel_cols(n, dtype, **cfg) > (
+        cfg["bn"] if dtype == BF16 else 0)
+    symm = jax.jit(lambda s, b: ops.symm(s, b, **cfg))
+    compiled = symm.lower(_spec((m, m), one_chip, dtype),
+                          _spec((m, n), one_chip, dtype)).compile()
+    assert _kernels(compiled) == 1
+
+
 def _compiles(fn, *args) -> bool:
     try:
         jax.jit(fn).lower(*args).compile()

@@ -98,7 +98,9 @@ def test_planner_spans_are_on_a_host_plane_with_one_id_per_call(
                     events.append((ev.start_ns, ev.end_ns, ev.name,
                                    dict(ev.stats)["id"]))
     names = {name for _, _, name, _ in events}
-    assert names == {n for n in snap if n.startswith(tracing.PREFIX)}
+    # Spans aggregate as (count, total, self) tuples; counters are ints.
+    assert names == {n for n, v in snap.items()
+                     if n.startswith(tracing.PREFIX) and isinstance(v, tuple)}
     calls = sorted(e for e in events if e[2] == "repro.planner.call")
     assert len(calls) == 2 and calls[0][3] != calls[1][3]
     for start, end, _, rid in calls:

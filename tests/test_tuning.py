@@ -449,7 +449,7 @@ def test_shape_config_pads_no_further_than_128_and_fits_vmem(kind):
 def test_shape_config_halves_the_widest_edge_to_fit_vmem(monkeypatch):
     from repro.core import tuning
     dims = (3072, 8192)
-    # Fits 512×256 symm tiles in bf16 (2.9 MB) but not 512×512 (4.2 MB).
+    # Fits 512×256 symm tiles in bf16 (3.1 MB) but not 512×512 (4.7 MB).
     small = HardwareSpec(name="test", peak_flops=1e12, hbm_bw=1e11,
                          link_bw=1e9, vmem_bytes=3_500_000)
     assert kernel_vmem_bytes("symm", dims, {"bm": 512, "bn": 512},
@@ -464,6 +464,73 @@ def test_shape_config_halves_the_widest_edge_to_fit_vmem(monkeypatch):
                                  dtype_bytes=2) <= small.vmem_bytes
     finally:
         tuning.shape_config.cache_clear()
+
+
+#: The symm kernel's (m, n) in ``aatb.ns_widths`` and in the paper box
+#: (chipbench/traffic): S is d0 × d0 and B d0 × d2.
+NS_WIDTHS_SYMM = [(3072, 3072), (3072, 8192), (4096, 4096), (512, 4096),
+                  (4096, 11008)]
+PAPER_BOX_SYMM = [(215, 1178), (239, 1038), (145, 828), (715, 439),
+                  (482, 1193), (995, 151), (349, 1000), (591, 795),
+                  (855, 833), (1181, 1023), (120, 987), (1085, 181),
+                  (531, 684), (384, 297), (497, 461), (549, 271)]
+
+
+def test_symm_vmem_bytes_counts_the_panel_accumulator_and_output():
+    """Each column the panel adds costs a bm-row column of the fp32
+    accumulator (4 B an element) and of the double-buffered B and output
+    panels (2 × 2 B each in bf16)."""
+    from repro.kernels import ops
+    from repro.kernels.symm import symm_vmem_bytes
+    dims, cfg = (4096, 11008), {"bm": 512, "bn": 256}
+    panel = ops.symm_panel_cols(11008, "bfloat16", 512, 256)
+    assert panel > 256
+    need = kernel_vmem_bytes("symm", dims, cfg, dtype_bytes=2)
+    assert need == symm_vmem_bytes(512, panel, dtype_bytes=2)
+    assert need - symm_vmem_bytes(512, 256, dtype_bytes=2) == \
+        512 * (panel - 256) * (4 + 2 * 2 + 2 * 2)
+
+
+@pytest.mark.parametrize("dims", NS_WIDTHS_SYMM + PAPER_BOX_SYMM, ids=str)
+def test_symm_panel_fits_vmem_at_the_cell_shapes(dims):
+    """At the tiles each cell runs, in bfloat16 and float32: the panel is
+    whole ``bn`` blocks, fits this device's kernel VMEM budget, and
+    computes under 3 % of n beyond the padded B."""
+    import jax.numpy as jnp
+
+    from repro.core.perfmodel import hardware_spec
+    from repro.core.tuning import shape_config
+    from repro.kernels import ops
+    from repro.kernels.symm import symm_vmem_bytes
+    budget = hardware_spec().vmem_bytes
+    for dtype in (jnp.dtype("bfloat16"), jnp.dtype("float32")):
+        cfg = dict(DEFAULT_CONFIGS["symm"], **(shape_config(
+            "symm", dims, dtype_bytes=dtype.itemsize) or {}))
+        _, n = padded_dims("symm", dims, cfg)
+        panel = ops.symm_panel_cols(dims[1], dtype, **cfg)
+        assert panel % cfg["bn"] == 0 and panel >= cfg["bn"]
+        assert symm_vmem_bytes(cfg["bm"], panel,
+                               dtype_bytes=dtype.itemsize) <= budget
+        assert kernel_vmem_bytes("symm", dims, cfg,
+                                 dtype_bytes=dtype.itemsize) <= budget
+        assert -(-n // panel) * panel - n < 0.03 * n
+
+
+def test_symm_panel_cuts_the_s_fetches_at_yi_9b_mlp_width():
+    """(4096, 11008) at 512 × 256 in bf16: n/bn = 43 is prime, so no
+    panel of whole blocks divides n. One S tile a grid step: 2752 fetches
+    at one 256-column block a step, ⌈11008/panel⌉·8·8 with the panel."""
+    from repro.kernels.symm import symm_panel
+    panel = symm_panel(11008, 512, 256, dtype_bytes=2, budget=16 << 20)
+    mb = 4096 // 512
+    fetches = -(-11008 // panel) * mb * mb
+    assert 11008 // 256 * mb * mb == 2752
+    assert panel % 256 == 0 and panel >= 1024
+    assert fetches <= 704
+    assert -(-11008 // panel) * panel - 11008 < 0.03 * 11008
+    # Past the VMEM budget the panel is one block again.
+    assert symm_panel(11008, 512, 256, dtype_bytes=2,
+                      budget=1 << 20) == 256
 
 
 def test_shape_config_is_memoised_and_read_only():
